@@ -1,6 +1,7 @@
-"""Overlay-executor micro-benchmark: work-items/s through the Pallas
-(interpret-mode on CPU) path vs the compiled-mode jnp path, plus the
-analytic model of the mapped overlay (GOPS at II=1)."""
+"""Overlay-executor micro-benchmark: wall time of the Pallas executor path
+(interpreted on the CPU backend, compiled elsewhere) vs the compiled-mode
+jnp path, plus the analytic model of the mapped overlay (GOPS at II=1).
+Rows name the backend they ran on."""
 
 from __future__ import annotations
 
@@ -34,6 +35,9 @@ def run() -> List[Dict]:
 
         import jax
         import jax.numpy as jnp
+
+        from repro.kernels import interpret_mode
+        pallas = "pallas_interpret" if interpret_mode() else "pallas"
         jxs = [jnp.asarray(x) for x in xs]
         compiled_mode = jax.jit(lambda *a: tuple(ck.dfg.evaluate(list(a))))
         us_compiled = _time(lambda: jax.block_until_ready(
@@ -43,7 +47,8 @@ def run() -> List[Dict]:
             "name": f"overlay_exec/{name}",
             "us_per_call": us_compiled,
             "derived": (f"compiled_mode={us_compiled:.0f}us "
-                        f"pallas_interpret={us_pallas:.0f}us "
+                        f"{pallas}={us_pallas:.0f}us "
+                        f"backend={jax.default_backend()} "
                         f"items={n} "
                         f"model_gops={ck.throughput_gops():.1f}"),
         })

@@ -66,6 +66,9 @@ def _run_child(persist_dir: str, kernels) -> Dict:
     env = dict(os.environ)
     src = str(Path(__file__).resolve().parents[1] / "src")
     env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
+    # the children only build overlay bitstreams: keep them off the chip,
+    # which a parent that has touched JAX holds
+    env["JAX_PLATFORMS"] = "cpu"
     out = subprocess.run([sys.executable, "-c", _CHILD, cfg], env=env,
                          capture_output=True, text=True, timeout=600)
     if out.returncode != 0:

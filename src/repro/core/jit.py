@@ -126,11 +126,14 @@ class CompiledKernel:
         arrs = [np.asarray(x, np.float32) for x in inputs]
         return _unpack(self.dfg.evaluate(arrs))
 
-    def run_overlay(self, *inputs, interpret: bool = True):
-        """Execute through the Pallas overlay-executor kernel."""
+    def run_overlay(self, *inputs, interpret: Optional[bool] = None,
+                    pad_to: int = 0, pad_regs: int = 0):
+        """Execute through the Pallas overlay-executor kernel, padded to
+        ``pad_to`` instructions and ``pad_regs`` registers if given."""
         from repro.kernels.overlay_exec import ops
         return _unpack(ops.execute(self.program, list(inputs),
-                                   interpret=interpret))
+                                   interpret=interpret, pad_to=pad_to,
+                                   pad_regs=pad_regs))
 
 
 def _unpack(outs: List[Any]):

@@ -90,7 +90,7 @@ class CommandQueue:
     """
 
     def __init__(self, context: "Context", in_order: bool = True,
-                 use_overlay_executor: bool = False,
+                 use_overlay_executor: Optional[bool] = None,
                  tenant: Optional[str] = None):
         self.ctx = context
         self.device = context.device

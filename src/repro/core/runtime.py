@@ -52,6 +52,7 @@ from repro.core.jit import CompiledKernel, jit_compile
 from repro.core.options import CompileOptions
 from repro.core.overlay import OverlaySpec
 from repro.core.recovery import CircuitBreaker
+from repro.kernels import interpret_mode
 
 # modelled compile-time guess (µs) for a kernel the fleet has never built —
 # the order of a cold template build; refined per kernel by an EWMA of
@@ -164,6 +165,9 @@ class Context:
         # OUTSIDE the context lock (the hook takes the fleet lock; taking it
         # under the context lock would invert the fleet→context lock order)
         self.on_release: Optional[Callable[["Program"], None]] = None
+        # the contexts whose resident programs share the process's compiled
+        # overlay executors with this one's (the Scheduler sets its fleet)
+        self.fleet: List["Context"] = [self]
         # modelled overlay-engine timeline, shared by every CommandQueue on
         # this context: busy intervals (sorted), the configuration-switch
         # history (ascending), and the running end-of-timeline.  Queues on
@@ -268,7 +272,7 @@ class Context:
 
     # -------------------------------------------------------------- queues
     def create_queue(self, in_order: bool = True,
-                     use_overlay_executor: bool = False,
+                     use_overlay_executor: Optional[bool] = None,
                      tenant: Optional[str] = None):
         from repro.core.queue import CommandQueue
         return CommandQueue(self, in_order=in_order,
@@ -313,6 +317,10 @@ class Program:
         # free-resource level (fu, io) at the last re-inflation attempt that
         # produced no growth; retried only once more fabric than that frees
         self.grow_failed_free: Optional[tuple] = None  # lock: any(_lock)
+        # (n_instr, n_regs, n_in, n_out) this program last ran at on the
+        # Pallas executor; None until it has.  Read racily by peers picking
+        # a signature to share: a stale read only costs a compile
+        self.exec_signature: Optional[tuple] = None
 
     def create_kernel(self) -> "Kernel":
         if self.released:
@@ -322,6 +330,17 @@ class Program:
     def configure_overlay(self) -> float:
         """'Load the bitstream': returns modelled config time in µs."""
         return self.compiled.bitstream.load_time_us()
+
+    def executor_signature(self) -> tuple:
+        """The executor signature this program runs at: one a resident
+        program of the fleet already runs at where
+        :func:`~repro.kernels.overlay_exec.ops.shared_signature` allows —
+        swapping to this program then compiles nothing — else its own."""
+        from repro.kernels.overlay_exec import ops
+        resident = {p.exec_signature for c in self.ctx.fleet
+                    for p in list(c.programs)
+                    if p.exec_signature is not None}
+        return ops.shared_signature(self.compiled.program, resident)
 
     def release(self) -> None:
         """Credit the program's FUs/IO back to the device ledger.
@@ -365,8 +384,12 @@ class Kernel:
     def work_items(self) -> int:
         return int(self.args[0].data.size) if self.args else 1
 
-    def enqueue(self, use_overlay_executor: bool = False):
-        """clEnqueueNDRangeKernel: run over all work-items of the buffers."""
+    def enqueue(self, use_overlay_executor: Optional[bool] = None):
+        """clEnqueueNDRangeKernel: run over all work-items of the buffers.
+
+        ``use_overlay_executor=None`` lets the backend decide
+        (:func:`repro.kernels.interpret_mode`): the compiled Pallas executor
+        on an accelerator, the NumPy reference on the CPU backend."""
         if self.program.released:
             raise RuntimeError_(
                 "kernel's program was released; its fabric may already be "
@@ -376,8 +399,12 @@ class Kernel:
         if len(ins) != len(ck.dfg.inputs):
             raise RuntimeError_(
                 f"kernel expects {len(ck.dfg.inputs)} buffers, got {len(ins)}")
+        if use_overlay_executor is None:
+            use_overlay_executor = not interpret_mode()
         if use_overlay_executor:
-            outs = ck.run_overlay(*ins)
+            sig = self.program.executor_signature()
+            outs = ck.run_overlay(*ins, pad_to=sig[0], pad_regs=sig[1])
+            self.program.exec_signature = sig
         else:
             outs = ck.run_reference(*ins)
         outs = outs if isinstance(outs, tuple) else (outs,)
@@ -465,6 +492,7 @@ class Scheduler:
         self._rebalancing = False  # lock: _lock
         for ctx in self.contexts.values():
             ctx.on_release = self._on_release
+            ctx.fleet = list(self.contexts.values())
 
     @property
     def devices(self) -> List[Device]:

@@ -241,7 +241,7 @@ class Session:
                  persist_dir: Optional[str] = None,
                  max_workers: int = 4,
                  policy: str = "makespan",
-                 use_overlay_executor: bool = False,
+                 use_overlay_executor: Optional[bool] = None,
                  faults: Optional[FaultPlan] = None,
                  retry: Optional[RetryPolicy] = None,
                  remote=None,
@@ -258,6 +258,9 @@ class Session:
             # lookup to the local tiers, never fails a build
             self.scheduler.cache.remote = remote
         self.platform = Platform(list(self.scheduler.devices))
+        # None: the backend decides per enqueue (repro.kernels.interpret_mode
+        # — compiled Pallas executor on an accelerator, NumPy reference on
+        # the CPU); True/False force one path
         self.use_overlay_executor = use_overlay_executor
         # chaos + self-healing plane: the fault plan (if any) is activated
         # thread-locally around every worker-pool build and every enqueue;

@@ -19,6 +19,8 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 
+from repro.kernels import interpret_mode
+
 NEG_INF = -1e30
 
 
@@ -40,10 +42,8 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, *, bq: int, bk: int, skv: int,
 
     def body(ki, carry):
         m_prev, l_prev, acc = carry
-        k = pl.load(k_ref, (pl.dslice(0, 1), pl.dslice(ki * bk, bk),
-                            slice(None)))[0].astype(jnp.float32)  # (BK, D)
-        v = pl.load(v_ref, (pl.dslice(0, 1), pl.dslice(ki * bk, bk),
-                            slice(None)))[0].astype(jnp.float32)
+        k = k_ref[0, pl.ds(ki * bk, bk), :].astype(jnp.float32)  # (BK, D)
+        v = v_ref[0, pl.ds(ki * bk, bk), :].astype(jnp.float32)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)  # (BQ,BK)
         k_pos = ki * bk + lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
@@ -78,7 +78,7 @@ def flash_attention(q, k, v, *, causal: bool = True,
                     window: Optional[int] = None,
                     scale: Optional[float] = None,
                     bq: int = 128, bk: int = 128,
-                    interpret: bool = True):
+                    interpret: Optional[bool] = None):
     """q: (B, Hq, Sq, D); k,v: (B, Hkv, Skv, D) → (B, Hq, Sq, D)."""
     b, hq, sq, d = q.shape
     _, hkv, skv, _ = k.shape
@@ -106,6 +106,6 @@ def flash_attention(q, k, v, *, causal: bool = True,
         ],
         out_specs=pl.BlockSpec((1, bq_, d), lambda h, i: (h, i, 0)),
         out_shape=jax.ShapeDtypeStruct((b * hq, sq, d), q.dtype),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(qr, kr, vr)
     return out.reshape(b, hq, sq, d)

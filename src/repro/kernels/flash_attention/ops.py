@@ -1,5 +1,5 @@
 """Jit'd dispatch wrapper for attention: 'ref' (pure jnp, any backend) or
-'pallas' (the flash kernel; interpret=True on CPU)."""
+'pallas' (the flash kernel; interpreted on the CPU backend only)."""
 
 from __future__ import annotations
 
@@ -11,7 +11,7 @@ from repro.kernels.flash_attention.kernel import flash_attention
 
 def attention(q, k, v, *, causal: bool = True, window: Optional[int] = None,
               scale: Optional[float] = None, impl: str = "ref",
-              interpret: bool = True):
+              interpret: Optional[bool] = None):
     if impl == "ref":
         return _ref.attention(q, k, v, causal=causal, window=window,
                               scale=scale)

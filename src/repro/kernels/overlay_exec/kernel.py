@@ -19,12 +19,46 @@ kept ≤ ~2 MB by the wrapper's block-size choice.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import interpret_mode
+
+
+# opcode -> datapath, indexed by the OP_* constants of repro.core.program
+_OPS = (
+    lambda a_, b_, c_, i_: i_,                  # NOP: load immediate
+    lambda a_, b_, c_, i_: a_ + b_,             # ADD
+    lambda a_, b_, c_, i_: a_ - b_,             # SUB
+    lambda a_, b_, c_, i_: b_ - a_,             # RSUB
+    lambda a_, b_, c_, i_: a_ * b_,             # MUL
+    lambda a_, b_, c_, i_: a_ * b_ + c_,        # MULADD
+    lambda a_, b_, c_, i_: a_ * b_ - c_,        # MULSUB
+    lambda a_, b_, c_, i_: a_ * i_ + b_,        # IMULADD
+    lambda a_, b_, c_, i_: a_ * i_ - b_,        # IMULSUB
+    lambda a_, b_, c_, i_: a_,                  # PASS
+    lambda a_, b_, c_, i_: jnp.abs(a_),         # ABS
+    lambda a_, b_, c_, i_: -a_,                 # NEG
+    lambda a_, b_, c_, i_: jnp.minimum(a_, b_),  # MIN
+    lambda a_, b_, c_, i_: jnp.maximum(a_, b_),  # MAX
+)
+
+
+def _dispatch(op, lo: int, hi: int, args):
+    """Opcode dispatch as a balanced tree of two-way conds (depth
+    ceil(log2 14) = 4).  A flat ``lax.switch`` lowers to a chain of 13
+    nested conds, which overflows the TPU compiler's layout pass."""
+    if hi - lo == 1:
+        return _OPS[lo](*args)
+    mid = (lo + hi) // 2
+    return lax.cond(op < mid,
+                    lambda: _dispatch(op, lo, mid, args),
+                    lambda: _dispatch(op, mid, hi, args))
 
 
 def _exec_kernel(instr_ref, imm_ref, x_ref, o_ref, regs_ref, *,
@@ -35,38 +69,25 @@ def _exec_kernel(instr_ref, imm_ref, x_ref, o_ref, regs_ref, *,
         regs_ref[i, :] = x_ref[i, :]
 
     def body(k, carry):
-        op = instr_ref[k, 0]
-        d = instr_ref[k, 1]
-        a = instr_ref[k, 2]
-        b = instr_ref[k, 3]
-        c = instr_ref[k, 4]
-        imm_port = instr_ref[k, 5]
+        # the instruction table is flat (6*M,) int32: a 2-D (M, 6) SMEM
+        # operand would be padded far past its size
+        op = instr_ref[6 * k]
+        d = instr_ref[6 * k + 1]
+        a = instr_ref[6 * k + 2]
+        b = instr_ref[6 * k + 3]
+        c = instr_ref[6 * k + 4]
+        imm_port = instr_ref[6 * k + 5]
         imm = imm_ref[k]
 
-        va = pl.load(regs_ref, (pl.dslice(a, 1), slice(None)))
-        vb = pl.load(regs_ref, (pl.dslice(b, 1), slice(None)))
-        vc = pl.load(regs_ref, (pl.dslice(c, 1), slice(None)))
+        va = regs_ref[pl.ds(a, 1), :]
+        vb = regs_ref[pl.ds(b, 1), :]
+        vc = regs_ref[pl.ds(c, 1), :]
         immv = jnp.full_like(va, imm)
         vb = jnp.where(imm_port == 1, immv, vb)
         vc = jnp.where(imm_port == 2, immv, vc)
 
-        res = lax.switch(op, [
-            lambda a_, b_, c_, i_: i_,              # NOP: load immediate
-            lambda a_, b_, c_, i_: a_ + b_,         # ADD
-            lambda a_, b_, c_, i_: a_ - b_,         # SUB
-            lambda a_, b_, c_, i_: b_ - a_,         # RSUB
-            lambda a_, b_, c_, i_: a_ * b_,         # MUL
-            lambda a_, b_, c_, i_: a_ * b_ + c_,    # MULADD
-            lambda a_, b_, c_, i_: a_ * b_ - c_,    # MULSUB
-            lambda a_, b_, c_, i_: a_ * i_ + b_,    # IMULADD
-            lambda a_, b_, c_, i_: a_ * i_ - b_,    # IMULSUB
-            lambda a_, b_, c_, i_: a_,              # PASS
-            lambda a_, b_, c_, i_: jnp.abs(a_),     # ABS
-            lambda a_, b_, c_, i_: -a_,             # NEG
-            lambda a_, b_, c_, i_: jnp.minimum(a_, b_),  # MIN
-            lambda a_, b_, c_, i_: jnp.maximum(a_, b_),  # MAX
-        ], va, vb, vc, immv)
-        pl.store(regs_ref, (pl.dslice(d, 1), slice(None)), res)
+        res = _dispatch(op, 0, len(_OPS), (va, vb, vc, immv))
+        regs_ref[pl.ds(d, 1), :] = res
         return carry
 
     lax.fori_loop(0, n_instr, body, 0)
@@ -79,8 +100,10 @@ def _exec_kernel(instr_ref, imm_ref, x_ref, o_ref, regs_ref, *,
 @functools.partial(jax.jit, static_argnames=(
     "n_in", "n_out", "n_instr", "n_regs", "block", "interpret"))
 def overlay_execute(instrs, imms, x, *, n_in: int, n_out: int, n_instr: int,
-                    n_regs: int, block: int = 1024, interpret: bool = True):
-    """x: (n_in, N) f32, N a multiple of ``block`` → (n_out, N) f32."""
+                    n_regs: int, block: int = 1024,
+                    interpret: Optional[bool] = None):
+    """instrs: (6*n_instr,) i32; imms: (n_instr,) f32; x: (n_in, N) f32, N a
+    multiple of ``block`` → (n_out, N) f32."""
     n = x.shape[1]
     grid = (n // block,)
     kernel = functools.partial(_exec_kernel, n_in=n_in, n_out=n_out,
@@ -95,5 +118,5 @@ def overlay_execute(instrs, imms, x, *, n_in: int, n_out: int, n_instr: int,
             scratch_shapes=[pltpu.VMEM((n_regs, block), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct((n_out, n), jnp.float32),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(instrs, imms, x)

@@ -5,23 +5,29 @@ execution image: instructions plus final PASS moves that park each output in
 the last ``n_out`` register slots.  Programs padded to the same
 (n_instr, n_regs, n_in, n_out) signature share one compiled executable —
 swapping kernels is a scalar-operand change only (the reconfiguration
-benchmark measures exactly this).
+benchmark measures exactly this).  ``execute`` runs a program at its own
+signature unless the caller pads it; :func:`shared_signature` is how the
+runtime picks a resident program's signature for a swap.
 """
 
 from __future__ import annotations
 
-from typing import List, Sequence, Tuple
+from typing import Iterable, List, Optional, Sequence, Tuple
 
 import numpy as np
 
 from repro.core.program import OP_PASS, OverlayProgram
 
 _LANE = 128
+# a program takes a resident program's executable only if that costs it at
+# most this factor in executor passes (instructions) and register rows
+SHARE_MAX_PAD = 1.25
 
 
 def build_image(program: OverlayProgram, pad_to: int = 0,
                 pad_regs: int = 0) -> Tuple[np.ndarray, np.ndarray, int, int]:
-    """→ (instrs (M,6) i32, imms (M,) f32, n_regs_total, n_out)."""
+    """→ (instrs (M,6) i32, imms (M,) f32, n_regs_total, n_out).  The
+    executor takes ``instrs`` flattened to (6*M,)."""
     p = program
     n_out = len(p.out_slots)
     # layout: [program regs | (pad gap) | trash | outputs] — outputs always
@@ -50,6 +56,31 @@ def build_image(program: OverlayProgram, pad_to: int = 0,
     return instrs, imms, n_regs, n_out
 
 
+def signature(program: OverlayProgram) -> Tuple[int, int, int, int]:
+    """(n_instr, n_regs, n_in, n_out) of the program's own executor image:
+    programs run at one signature share one compiled executable."""
+    n_out = len(program.out_slots)
+    return (program.n_instr + n_out, program.n_regs + 1 + n_out,
+            len(program.in_slots), n_out)
+
+
+def shared_signature(program: OverlayProgram,
+                     resident: Iterable[Tuple[int, int, int, int]]
+                     ) -> Tuple[int, int, int, int]:
+    """The signature to run ``program`` at, given the signatures of the
+    programs resident on the executor: the smallest of them that holds the
+    program's image with at most ``SHARE_MAX_PAD`` times its own
+    instructions and registers, else the program's own.  Every padded
+    instruction is one more executor pass over each block on every
+    launch, so a program pads only where that saves a compile."""
+    own = signature(program)
+    fits = [s for s in resident
+            if s[2:] == own[2:]
+            and own[0] <= s[0] <= SHARE_MAX_PAD * own[0]
+            and own[1] <= s[1] <= SHARE_MAX_PAD * own[1]]
+    return min(fits, default=own)
+
+
 def _pick_block(n: int, n_regs: int, n_in: int, n_out: int,
                 vmem_budget: int = 2 << 20) -> int:
     """Largest lane-aligned block whose register file fits the VMEM budget."""
@@ -59,10 +90,11 @@ def _pick_block(n: int, n_regs: int, n_in: int, n_out: int,
 
 
 def execute(program: OverlayProgram, inputs: Sequence, *,
-            interpret: bool = True, pad_to: int = 0,
+            interpret: Optional[bool] = None, pad_to: int = 0,
             pad_regs: int = 0) -> List[np.ndarray]:
     """Run an OverlayProgram over flat work-item arrays via the Pallas
-    executor. Accepts any shaped arrays; work-items = flattened elements."""
+    executor. Accepts any shaped arrays; work-items = flattened elements.
+    ``pad_to``/``pad_regs`` pad the image to a shared signature."""
     import jax.numpy as jnp
 
     from repro.kernels.overlay_exec.kernel import overlay_execute
@@ -81,7 +113,7 @@ def execute(program: OverlayProgram, inputs: Sequence, *,
         x = np.concatenate([x, np.zeros((n_in, n_pad - n), np.float32)],
                            axis=1)
 
-    out = overlay_execute(jnp.asarray(instrs), jnp.asarray(imms),
+    out = overlay_execute(jnp.asarray(instrs.ravel()), jnp.asarray(imms),
                           jnp.asarray(x),
                           n_in=n_in, n_out=n_out,
                           n_instr=int(instrs.shape[0]), n_regs=n_regs,

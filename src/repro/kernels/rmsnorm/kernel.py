@@ -8,10 +8,13 @@ instead of XLA's potential separate reduce + scale passes.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels import interpret_mode
 
 
 def _rms_kernel(x_ref, w_ref, o_ref, *, eps: float):
@@ -23,7 +26,7 @@ def _rms_kernel(x_ref, w_ref, o_ref, *, eps: float):
 
 @functools.partial(jax.jit, static_argnames=("eps", "block_rows", "interpret"))
 def rmsnorm(x, weight, eps: float = 1e-6, block_rows: int = 256,
-            interpret: bool = True):
+            interpret: Optional[bool] = None):
     """x: (..., D); weight: (D,)."""
     shape = x.shape
     d = shape[-1]
@@ -42,6 +45,6 @@ def rmsnorm(x, weight, eps: float = 1e-6, block_rows: int = 256,
                   pl.BlockSpec((d,), lambda i: (0,))],
         out_specs=pl.BlockSpec((br, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n_pad, d), x.dtype),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(xr, weight)
     return out[:n].reshape(shape)

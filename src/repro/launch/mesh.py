@@ -11,24 +11,52 @@ state (the dry-run must set XLA_FLAGS before first jax init).
 
 from __future__ import annotations
 
+from typing import Dict
+
 import jax
+from jax.sharding import AxisType
+
+
+def _mesh(shape, axes, devices=None):
+    # Auto axes: shardings propagate through the compiler (GSPMD), which
+    # the models' param/cache specs are written for
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _mesh(shape, axes)
 
 
-def make_host_mesh(model_shards: int = 1):
-    """Smoke-test mesh on whatever devices exist (usually 1 CPU device)."""
-    n = len(jax.devices())
+def make_host_mesh(model_shards: int = 1, devices=None):
+    """Mesh over ``devices`` (default: every device this host sees):
+    (data, model) with the requested model sharding (``plan_cluster``)."""
+    devices = list(devices) if devices is not None else jax.devices()
     from repro.core.replicate import plan_cluster
-    plan = plan_cluster(n, model_shards)
-    return jax.make_mesh(plan.mesh_shape, ("data", "model"))
+    plan = plan_cluster(len(devices), model_shards)
+    n = plan.mesh_shape[0] * plan.mesh_shape[1]
+    return _mesh(plan.mesh_shape, ("data", "model"), devices=devices[:n])
 
 
-# TPU v5e hardware constants for the roofline (per chip)
-PEAK_FLOPS_BF16 = 197e12        # FLOP/s
-HBM_BW = 819e9                  # B/s
-ICI_BW = 50e9                   # B/s per link direction
+# Per-chip peaks, keyed by ``jax.Device.device_kind``.  Source: Google
+# Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 16 GB of HBM at
+# 819 GB/s, 1,600 Gbit/s of inter-chip interconnect (4 links, taken here
+# as 50 GB/s per link direction).
+PEAKS: Dict[str, Dict[str, float]] = {
+    "TPU v5 lite": dict(flops_bf16=197e12, hbm_bw=819e9, ici_bw=50e9),
+}
+# the chip the production meshes above are built from
+PRODUCTION_KIND = "TPU v5 lite"
+
+
+def chip_peaks(device_kind: str) -> Dict[str, float]:
+    """Published peaks of one chip of ``device_kind``; a kind without a
+    row raises rather than borrowing another chip's numbers."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no published peaks for device kind "
+                       f"{device_kind!r}; add a row to PEAKS with its "
+                       f"source") from None

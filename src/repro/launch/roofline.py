@@ -16,7 +16,9 @@ import dataclasses
 import re
 from typing import Any, Dict
 
-from repro.launch.mesh import HBM_BW, ICI_BW, PEAK_FLOPS_BF16
+from repro.launch.mesh import PRODUCTION_KIND, chip_peaks
+
+_PEAK = chip_peaks(PRODUCTION_KIND)
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2, "f16": 2,
@@ -86,15 +88,15 @@ class Roofline:
 
     @property
     def compute_s(self) -> float:
-        return self.flops_per_device / PEAK_FLOPS_BF16
+        return self.flops_per_device / _PEAK["flops_bf16"]
 
     @property
     def memory_s(self) -> float:
-        return self.bytes_per_device / HBM_BW
+        return self.bytes_per_device / _PEAK["hbm_bw"]
 
     @property
     def collective_s(self) -> float:
-        return self.coll_bytes_per_device / ICI_BW
+        return self.coll_bytes_per_device / _PEAK["ici_bw"]
 
     @property
     def dominant(self) -> str:
@@ -116,7 +118,7 @@ class Roofline:
     @property
     def mfu(self) -> float:
         """Roofline-model MFU: useful FLOPs / (chips · peak · step_s)."""
-        denom = self.n_devices * PEAK_FLOPS_BF16 * self.step_s
+        denom = self.n_devices * _PEAK["flops_bf16"] * self.step_s
         return self.model_flops_global / denom if denom else 0.0
 
     def to_dict(self) -> Dict[str, Any]:

@@ -12,21 +12,118 @@ occupancy and per-SLO-class modelled latency from
   PYTHONPATH=src python -m repro.launch.serve --arch llama3-8b --reduced \
       --requests 24 --gen 8
 
-The pre-PR-9 raw-JAX driver (token-recurrent prefill + argmax/categorical
+The older raw-JAX driver (token-recurrent prefill + argmax/categorical
 decode through ``make_serve_step``, never touching the Session) is kept
 behind ``--legacy`` with a DeprecationWarning, parity-tested in
-``tests/test_launch_serve.py``.
+``tests/test_launch_serve.py``.  Its loop, :func:`decode_loop`, is what
+``chip_smoke.py`` drives at a zoo model's published width.
 """
 
 from __future__ import annotations
 
 import argparse
+import functools
 import time
 import warnings
+from typing import Any, Dict
 
 import numpy as np
 
 from repro.configs.registry import ALL_ARCHS, get_arch, reduced_config
+from repro.launch.compile_cache import enable_compile_cache
+
+
+def decode_loop(cfg, mesh, *, batch: int, prompt_len: int, gen: int,
+                temperature: float = 0.0) -> Dict[str, Any]:
+    """The raw-JAX serving loop: token-recurrent prefill and argmax (or
+    categorical) decode through ``make_serve_step``, on ``mesh``.
+
+    Parameters are drawn by one jitted init with the mesh's output
+    shardings, so every device draws only its own shard and the f32
+    normals fuse into the bf16 cast.  Init and step are compiled ahead of
+    time, so the returned run times exclude compilation.  Returns the
+    model, its parameters, the prompt, the logits after the last prompt
+    token, the generated tokens and the seconds of each stage."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from repro.models.registry import build_model
+    from repro.train.step import make_serve_step
+
+    def _named(tree):
+        return jax.tree.map(lambda s: NamedSharding(mesh, s), tree,
+                            is_leaf=lambda x: isinstance(x, P))
+
+    model = build_model(cfg)
+    p_sh = _named(model.param_specs())
+    c_sh = _named(model.cache_specs(model_axis=mesh.shape["model"]))
+    rep = NamedSharding(mesh, P())
+    max_len = prompt_len + gen
+
+    key = jax.random.PRNGKey(0)
+    t0 = time.perf_counter()
+    init = jax.jit(model.init, out_shardings=p_sh).lower(key).compile()
+    new_cache = jax.jit(functools.partial(model.init_cache, batch, max_len),
+                        out_shardings=c_sh).lower().compile()
+    t_compile = time.perf_counter() - t0
+    t0 = time.perf_counter()
+    params, cache = init(key), new_cache()
+    jax.block_until_ready((params, cache))
+    t_init = time.perf_counter() - t0
+
+    prompt = np.random.default_rng(0).integers(
+        0, cfg.vocab, (batch, prompt_len), np.int32)
+
+    def put(x):
+        return jax.device_put(x, rep)
+
+    t0 = time.perf_counter()
+    serve_step = jax.jit(make_serve_step(model), donate_argnums=(1,),
+                         out_shardings=(rep, c_sh)).lower(
+        params, cache, put(prompt[:, :1]), put(np.int32(0))).compile()
+    t_compile += time.perf_counter() - t0
+
+    # prefill: feed prompt tokens one step at a time through the decode
+    # path (token-recurrent prefill; blockwise prefill is the prefill_*
+    # shape)
+    t0 = time.perf_counter()
+    logits = None
+    for i in range(prompt_len):
+        logits, cache = serve_step(params, cache, put(prompt[:, i:i + 1]),
+                                   put(np.int32(i)))
+    prompt_logits = np.asarray(logits, np.float32)
+    t_prefill = time.perf_counter() - t0
+
+    def pick(logits, key):
+        key, sub = jax.random.split(key)
+        if temperature > 0:
+            nxt = jax.random.categorical(sub, logits / temperature, axis=-1)
+        else:
+            nxt = jnp.argmax(logits, axis=-1)
+        return nxt.astype(jnp.int32)[:, None], key
+
+    key_s = put(key)
+    t0 = time.perf_counter()
+    pick = jax.jit(pick, out_shardings=(rep, rep)).lower(logits,
+                                                         key_s).compile()
+    t_compile += time.perf_counter() - t0
+
+    out_tokens = []
+    t0 = time.perf_counter()
+    for i in range(gen):
+        nxt, key_s = pick(logits, key_s)
+        out_tokens.append(nxt)
+        logits, cache = serve_step(params, cache, nxt,
+                                   put(np.int32(prompt_len + i)))
+    jax.block_until_ready(logits)
+    t_gen = time.perf_counter() - t0
+
+    return dict(model=model, params=params, prompt=prompt,
+                prompt_logits=prompt_logits,
+                tokens=np.concatenate(out_tokens, axis=1),
+                init_s=t_init, compile_s=t_compile, prefill_s=t_prefill,
+                decode_s=t_gen)
 
 
 def _legacy_main(args) -> None:
@@ -37,69 +134,20 @@ def _legacy_main(args) -> None:
         "will be removed once the overlay path covers sampling. Use the "
         "default repro.serve path instead.",
         DeprecationWarning, stacklevel=2)
-    import jax
-    import jax.numpy as jnp
-    from jax.sharding import NamedSharding, PartitionSpec as P
-
     from repro.launch.mesh import make_host_mesh
-    from repro.models.registry import build_model
-    from repro.train.step import make_serve_step
-
-    def _named(mesh, tree):
-        return jax.tree.map(lambda s: NamedSharding(mesh, s), tree,
-                            is_leaf=lambda x: isinstance(x, P))
 
     cfg = get_arch(args.arch)
     if args.reduced:
         cfg = reduced_config(cfg)
-    model = build_model(cfg)
-    mesh = make_host_mesh(args.model_shards)
-
-    key = jax.random.PRNGKey(0)
-    params = jax.device_put(model.init(key),
-                            _named(mesh, model.param_specs()))
-    max_len = args.prompt_len + args.gen
-    cache = jax.device_put(model.init_cache(args.batch, max_len),
-                           _named(mesh, model.cache_specs()))
-    serve_step = jax.jit(make_serve_step(model), donate_argnums=(1,))
-
-    prompt = np.random.default_rng(0).integers(
-        0, cfg.vocab, (args.batch, args.prompt_len), np.int32)
-
-    # prefill: feed prompt tokens one step at a time through the decode
-    # path (token-recurrent prefill; blockwise prefill is the prefill_*
-    # shape)
-    t0 = time.perf_counter()
-    logits = None
-    for i in range(args.prompt_len):
-        logits, cache = serve_step(params, cache,
-                                   jnp.asarray(prompt[:, i:i + 1]),
-                                   jnp.int32(i))
-    t_prefill = time.perf_counter() - t0
-
-    out_tokens = []
-    t0 = time.perf_counter()
-    key_s = key
-    for i in range(args.gen):
-        if args.temperature > 0:
-            key_s, sub = jax.random.split(key_s)
-            nxt = jax.random.categorical(sub, logits / args.temperature,
-                                         axis=-1)
-        else:
-            nxt = jnp.argmax(logits, axis=-1)
-        nxt = nxt[:, None].astype(jnp.int32)
-        out_tokens.append(np.asarray(nxt))
-        logits, cache = serve_step(params, cache, nxt,
-                                   jnp.int32(args.prompt_len + i))
-    jax.block_until_ready(logits)
-    t_gen = time.perf_counter() - t0
-
-    gen = np.concatenate(out_tokens, axis=1)
+    out = decode_loop(cfg, make_host_mesh(args.model_shards),
+                      batch=args.batch, prompt_len=args.prompt_len,
+                      gen=args.gen, temperature=args.temperature)
+    t_prefill, t_gen = out["prefill_s"], out["decode_s"]
     print(f"arch={args.arch} batch={args.batch} "
           f"prefill {args.prompt_len} tok in {t_prefill:.2f}s | "
           f"decode {args.gen} tok in {t_gen:.2f}s "
           f"({args.batch * args.gen / t_gen:.1f} tok/s)")
-    print("sample:", gen[0, :16].tolist())
+    print("sample:", out["tokens"][0, :16].tolist())
 
 
 def serve_overlay(arch: str, n_requests: int, gen: int, slo: str,
@@ -150,6 +198,7 @@ def main() -> None:
     ap.add_argument("--slo", choices=("realtime", "standard", "batch"),
                     default="standard")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.legacy:
         _legacy_main(args)

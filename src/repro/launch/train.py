@@ -20,6 +20,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs.registry import ALL_ARCHS, get_arch, reduced_config
 from repro.data.pipeline import SyntheticTokens
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh, make_production_mesh
 from repro.models.registry import build_model, input_shardings
 from repro.optim.adamw import AdamWConfig
@@ -47,6 +48,7 @@ def main() -> None:
     ap.add_argument("--remat", default="full")
     ap.add_argument("--metrics-out", default=None)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_arch(args.arch)
     if args.reduced:

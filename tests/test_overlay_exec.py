@@ -79,3 +79,59 @@ def test_against_compiled_mode():
     got = ops.execute(prog, xs)
     for w, gg in zip(want, got):
         np.testing.assert_allclose(gg, np.asarray(w), rtol=RTOL, atol=ATOL)
+
+
+def _suite_program(name):
+    from repro.configs.paper_suite import BENCHMARKS
+    from repro.core.jit import jit_compile
+    from repro.core.options import CompileOptions
+    from repro.core.overlay import OverlaySpec
+    return jit_compile(BENCHMARKS[name][0],
+                       OverlaySpec(width=8, height=8, dsp_per_fu=2),
+                       opts=CompileOptions(max_replicas=1)).program
+
+
+@pytest.mark.parametrize("resident, shared", [
+    ([], None),
+    ([(6, 8, 1, 1)], (6, 8, 1, 1)),
+    ([(16, 16, 1, 1), (6, 8, 1, 1)], (6, 8, 1, 1)),
+    ([(16, 16, 1, 1)], None),        # more than SHARE_MAX_PAD times its own
+    ([(4, 8, 1, 1)], None),          # too short to hold it
+    ([(6, 8, 2, 1)], None),          # another IO arity
+], ids=["alone", "fits", "smallest", "too_long", "too_short", "arity"])
+def test_shared_signature(resident, shared):
+    prog = _suite_program("poly1")
+    own = ops.signature(prog)
+    assert own == (prog.n_instr + 1, prog.n_regs + 2, 1, 1) == (5, 7, 1, 1)
+    assert ops.shared_signature(prog, resident) == (shared or own)
+
+
+def test_session_swap_shares_executable():
+    """Through the Session, poly1 runs at the signature of the resident
+    chebyshev and so compiles no executor; poly2 fits none and runs at its
+    own."""
+    from repro.configs.paper_suite import BENCHMARKS
+    from repro.core.options import CompileOptions
+    from repro.core.overlay import OverlaySpec
+    from repro.core.runtime import Device
+    from repro.core.session import Session
+    from repro.kernels.overlay_exec.kernel import overlay_execute
+
+    spec = OverlaySpec(width=8, height=8, dsp_per_fu=2)
+    x = np.linspace(-1, 1, 1024).astype(np.float32)
+    progs, compiled = {}, {}
+    with Session([Device("ovl0", spec), Device("ovl1", spec)],
+                 use_overlay_executor=True) as sess:
+        for name in ("chebyshev", "poly1", "poly2"):
+            fut = sess.compile(BENCHMARKS[name][0],
+                               CompileOptions(max_replicas=2))
+            n0 = overlay_execute._cache_size()
+            got = sess.enqueue(fut, x).wait()[0].read()
+            compiled[name] = overlay_execute._cache_size() - n0
+            progs[name] = fut.result()
+            np.testing.assert_allclose(got, BENCHMARKS[name][2](x),
+                                       rtol=RTOL, atol=1e-4)
+    sig = {n: p.exec_signature for n, p in progs.items()}
+    assert sig["poly1"] == sig["chebyshev"] == (6, 8, 1, 1)
+    assert compiled["poly1"] == 0
+    assert sig["poly2"] == ops.signature(progs["poly2"].compiled.program)
