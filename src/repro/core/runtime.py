@@ -53,6 +53,7 @@ from repro.core.options import CompileOptions
 from repro.core.overlay import OverlaySpec
 from repro.core.recovery import CircuitBreaker
 from repro.kernels import interpret_mode
+from repro.obs import trace as obs_trace
 
 # modelled compile-time guess (µs) for a kernel the fleet has never built —
 # the order of a cold template build; refined per kernel by an EWMA of
@@ -145,7 +146,8 @@ class Buffer:
         self.data = np.asarray(data, np.float32)
 
     def read(self) -> np.ndarray:
-        return self.data.copy()
+        with obs_trace.span("buffer:read", "launch"):
+            return self.data.copy()
 
 
 class Context:

@@ -573,43 +573,47 @@ class Session:
         a breaker trip — or outright device loss — heals the device
         (migrate resident Programs, re-enqueue lost events) and the retry
         lands on wherever the program now lives.  The loop is bounded by
-        the enqueue retry budget plus one healing hop per device."""
-        attempts = hops = 0
-        while True:
-            dev = prog.ctx.device.name
-            q = self.queue_for(tenant, dev)
-            try:
-                with faults_mod.activate(self.faults), \
-                        obs_trace.activate(self.tracer):
-                    ev = q.enqueue_kernel(
-                        prog.create_kernel().set_args(*bufs),
-                        wait_for=deps, label=label)
-                # a completed command is health evidence: resets the
-                # breaker's consecutive count / closes a half-open probe
-                self.scheduler.breakers[dev].record_success()
-                return ev
-            except DeviceLostError:
-                hops += 1
-                if hops > len(self.contexts):
-                    raise        # every device in the fleet is gone
-                self._heal_device(dev)
-                if prog.released or prog.ctx.device.name == dev:
-                    raise        # migration could not re-seat the program
-            except InjectedFault:
-                attempts += 1
-                tripped = self.scheduler.breakers[dev].record_failure()
-                if tripped:
-                    # consecutive failures say the device is sick even
-                    # though it still answers: evacuate it and retry the
-                    # command where the program migrated to
+        the enqueue retry budget plus one healing hop per device.  One
+        ``session:enqueue`` span covers it: less the executor's
+        ``launch:*`` spans inside it, that is the Session's and queue's
+        own bookkeeping."""
+        with obs_trace.activate(self.tracer), \
+                obs_trace.span("session:enqueue", "session"):
+            attempts = hops = 0
+            while True:
+                dev = prog.ctx.device.name
+                q = self.queue_for(tenant, dev)
+                try:
+                    with faults_mod.activate(self.faults):
+                        ev = q.enqueue_kernel(
+                            prog.create_kernel().set_args(*bufs),
+                            wait_for=deps, label=label)
+                    # a completed command is health evidence: resets the
+                    # breaker's consecutive count / closes a half-open probe
+                    self.scheduler.breakers[dev].record_success()
+                    return ev
+                except DeviceLostError:
+                    hops += 1
+                    if hops > len(self.contexts):
+                        raise        # every device in the fleet is gone
                     self._heal_device(dev)
                     if prog.released or prog.ctx.device.name == dev:
+                        raise        # migration could not re-seat the program
+                except InjectedFault:
+                    attempts += 1
+                    tripped = self.scheduler.breakers[dev].record_failure()
+                    if tripped:
+                        # consecutive failures say the device is sick even
+                        # though it still answers: evacuate it and retry the
+                        # command where the program migrated to
+                        self._heal_device(dev)
+                        if prog.released or prog.ctx.device.name == dev:
+                            raise
+                        continue
+                    if attempts > self.retry.enqueue_retries:
                         raise
-                    continue
-                if attempts > self.retry.enqueue_retries:
-                    raise
-                self.recovery.bump("enqueue_retries")
-                time.sleep(self.retry.backoff_s(attempts, key=dev))
+                    self.recovery.bump("enqueue_retries")
+                    time.sleep(self.retry.backoff_s(attempts, key=dev))
 
     # -------------------------------------------------------- device health
     def fail_device(self, name: str, at_us: Optional[float] = None) -> None:

@@ -17,6 +17,7 @@ from typing import Iterable, List, Optional, Sequence, Tuple
 import numpy as np
 
 from repro.core.program import OP_PASS, OverlayProgram
+from repro.obs import trace as obs_trace
 
 _LANE = 128
 # a program takes a resident program's executable only if that costs it at
@@ -94,29 +95,39 @@ def execute(program: OverlayProgram, inputs: Sequence, *,
             pad_regs: int = 0) -> List[np.ndarray]:
     """Run an OverlayProgram over flat work-item arrays via the Pallas
     executor. Accepts any shaped arrays; work-items = flattened elements.
-    ``pad_to``/``pad_regs`` pad the image to a shared signature."""
+    ``pad_to``/``pad_regs`` pad the image to a shared signature.
+
+    The host path is four spans (``repro.obs.trace``): ``launch:stage``
+    (the inputs as one float32 (n_in, n_pad) array), ``launch:h2d`` (the
+    transfers to the device), ``launch:wait`` (the executor's result
+    ready) and ``launch:d2h`` (the output back on the host)."""
     import jax.numpy as jnp
 
     from repro.kernels.overlay_exec.kernel import overlay_execute
 
-    arrs = [np.asarray(x, np.float32) for x in inputs]
-    shape = arrs[0].shape
-    n = int(np.prod(shape)) if shape else 1
-    x = np.stack([a.ravel() for a in arrs])           # (n_in, N)
-
     instrs, imms, n_regs, n_out = build_image(program, pad_to=pad_to,
                                               pad_regs=pad_regs)
-    n_in = x.shape[0]
-    block = _pick_block(n, n_regs, n_in, n_out)
-    n_pad = (n + block - 1) // block * block
-    if n_pad != n:
-        x = np.concatenate([x, np.zeros((n_in, n_pad - n), np.float32)],
-                           axis=1)
+    with obs_trace.span("launch:stage", "launch"):
+        arrs = [np.asarray(x, np.float32) for x in inputs]
+        shape = arrs[0].shape
+        n = int(np.prod(shape)) if shape else 1
+        x = np.stack([a.ravel() for a in arrs])           # (n_in, N)
+        n_in = x.shape[0]
+        block = _pick_block(n, n_regs, n_in, n_out)
+        n_pad = (n + block - 1) // block * block
+        if n_pad != n:
+            x = np.concatenate([x, np.zeros((n_in, n_pad - n), np.float32)],
+                               axis=1)
 
-    out = overlay_execute(jnp.asarray(instrs.ravel()), jnp.asarray(imms),
-                          jnp.asarray(x),
+    with obs_trace.span("launch:h2d", "launch"):
+        d_instrs, d_imms, d_x = (jnp.asarray(instrs.ravel()),
+                                 jnp.asarray(imms), jnp.asarray(x))
+    out = overlay_execute(d_instrs, d_imms, d_x,
                           n_in=n_in, n_out=n_out,
                           n_instr=int(instrs.shape[0]), n_regs=n_regs,
                           block=block, interpret=interpret)
-    out = np.asarray(out)[:, :n]
-    return [out[j].reshape(shape) for j in range(n_out)]
+    with obs_trace.span("launch:wait", "launch"):
+        out.block_until_ready()
+    with obs_trace.span("launch:d2h", "launch"):
+        out = np.asarray(out)[:, :n]
+        return [out[j].reshape(shape) for j in range(n_out)]

@@ -3,10 +3,22 @@
 The tracer is *ambient*: like the fault plane (``repro.core.faults``) it
 is activated per-thread via a context manager, and every instrumentation
 point in the runtime asks the thread-local slot whether a tracer is
-active.  The disabled path is therefore exactly one TLS read — no locks,
-no allocation, no branching beyond the ``None`` check — which is what
-lets the probes live permanently on the warm hit path (gated at zero by
-``benchmarks/trace_overhead_perf.py``).
+active.
+
+Spans have two sinks:
+
+* the in-memory :class:`Tracer`, when one is active on the thread;
+* the JAX profiler, whenever it is collecting a trace
+  (``jax.profiler.start_trace``): each wall span is also a host
+  ``TraceAnnotation`` of the same name, on the profiler's clock, so the
+  program's spans sit beside the device's operations in one trace.
+
+With neither sink the disabled path is one TLS read and the profiler's
+own "is a trace being collected" check, returning one shared no-op — no
+allocation, no lock — which is what lets the probes live permanently on
+the warm hit path (gated by ``benchmarks/trace_overhead_perf.py``).  The
+profiler is imported lazily: until JAX is imported nothing can be
+collecting, and importing ``repro.obs`` does not import JAX.
 
 Two kinds of spans share one record type:
 
@@ -18,7 +30,8 @@ Two kinds of spans share one record type:
   books an interval on the *device* timeline using the simulator's µs
   clock (queue submit / config charge / kernel execution), so the
   exported Chrome trace shows host compile activity and modelled device
-  occupancy side by side.
+  occupancy side by side.  Modelled spans never reach the profiler: its
+  trace holds the real device only.
 
 ``Tracer(clock=...)`` accepts an injectable clock (µs since epoch of the
 tracer) so tests can produce byte-stable golden traces.
@@ -28,6 +41,7 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import sys
 import threading
 import time
 from typing import Any, Callable, Dict, List, Optional, Tuple
@@ -39,7 +53,8 @@ __all__ = [
 
 #: span categories used by the built-in instrumentation points
 #: (``docs/observability.md`` documents the full taxonomy).
-CATEGORIES = ("compile", "cache", "queue", "device", "serving", "session")
+CATEGORIES = ("compile", "cache", "queue", "device", "serving", "session",
+              "launch")
 
 
 @dataclasses.dataclass
@@ -207,8 +222,9 @@ def activate(tracer: Optional[Tracer]):
 
 class _NullSpan:
     """Shared no-op context manager returned by :func:`span` when no
-    tracer is active — supports the same ``sp[...] = v`` outcome
-    recording so call sites need no branches."""
+    tracer is active and no profiler trace is being collected — supports
+    the same ``sp[...] = v`` outcome recording so call sites need no
+    branches."""
 
     __slots__ = ()
 
@@ -224,14 +240,63 @@ class _NullSpan:
 
 _NULL_SPAN = _NullSpan()
 
+# ``jax.profiler.TraceAnnotation``, bound once JAX is imported (None
+# before: no JAX, no profiler session)
+_ANNOTATION = None
+
+
+def _bind_profiler() -> bool:
+    """Whether the JAX profiler is collecting a trace.  Until JAX is
+    imported nothing can be; once it is, bind the profiler's own check
+    as :data:`_collecting`, so later probes call it directly."""
+    global _ANNOTATION, _collecting
+    if "jax" not in sys.modules:
+        return False
+    from jax.profiler import TraceAnnotation
+    _ANNOTATION = TraceAnnotation
+    _collecting = TraceAnnotation.is_enabled
+    return _collecting()
+
+
+_collecting: Callable[[], bool] = _bind_profiler
+
+
+class _ProfilerSpan:
+    """A wall span written to the profiler's trace and, where a tracer is
+    active, recorded by it too: ``__enter__`` returns the tracer span's
+    args, or else the span itself, whose outcome writes are dropped."""
+
+    __slots__ = ("_mark", "_span")
+
+    def __init__(self, name: str, handle: Optional[_SpanHandle] = None):
+        self._mark = _ANNOTATION(name)
+        self._span = handle
+
+    def __enter__(self):
+        self._mark.__enter__()
+        return self if self._span is None else self._span.__enter__()
+
+    def __exit__(self, *exc):
+        try:
+            if self._span is not None:
+                self._span.__exit__(*exc)
+        finally:
+            self._mark.__exit__(*exc)
+        return False
+
+    def __setitem__(self, key, value):
+        pass
+
 
 def span(name: str, cat: str = "", **args):
-    """Open a wall span against the ambient tracer; a shared no-op when
-    tracing is disabled (one TLS read, no allocation)."""
+    """Open a wall span against the ambient tracer and, while it collects,
+    the JAX profiler; a shared no-op when neither records (one TLS read
+    and one profiler check, no allocation)."""
     tr = getattr(_TLS, "tracer", None)
     if tr is None:
-        return _NULL_SPAN
-    return tr.span(name, cat, **args)
+        return _ProfilerSpan(name) if _collecting() else _NULL_SPAN
+    handle = tr.span(name, cat, **args)
+    return _ProfilerSpan(name, handle) if _collecting() else handle
 
 
 def modelled(name: str, track: str, ts_us: float, dur_us: float,

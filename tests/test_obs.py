@@ -18,10 +18,15 @@ The load-bearing claims, each asserted here:
   * Session.stats() emits registered sections in deterministic name
     order and refuses names that would shadow a built-in section;
   * completions past their SLO class's target_p99_us are counted per
-    class in stats()["serving"] and in the metrics registry.
+    class in stats()["serving"] and in the metrics registry;
+  * while the JAX profiler collects, wall spans are host events of its
+    trace (nested on their thread), modelled spans never are, and the
+    executor launch and Session enqueue book their spans in order.
 """
 
+import glob
 import json
+import os
 import threading
 
 import numpy as np
@@ -38,7 +43,8 @@ from repro.core.runtime import Device
 from repro.core.session import Session, SessionError
 from repro.obs import (MetricsRegistry, ProfileStore, ReCutter, Tracer,
                        activate, active_tracer, chrome_trace, hot_profiles,
-                       profile_key, span, write_chrome_trace)
+                       modelled, profile_key, span, write_chrome_trace)
+from repro.kernels.overlay_exec import ops
 from repro.obs.trace import _NULL_SPAN
 from repro.serve import InferenceServer, Request
 from repro.serve.slo import SLOClass
@@ -123,6 +129,134 @@ def test_disabled_path_is_shared_noop():
         assert active_tracer() is tr
     assert active_tracer() is None
     assert tr.n_spans == 0
+
+
+def test_disabled_path_is_shared_noop_with_jax_imported():
+    """JAX imported but no profiler trace collected: still the shared
+    no-op, and outcome writes on it are dropped."""
+    import jax.profiler
+    assert not jax.profiler.TraceAnnotation.is_enabled()
+    sp = span("launch:stage", "launch")
+    assert sp is _NULL_SPAN
+    with sp as h:
+        h["items"] = 1
+
+
+def _profiled(body, tmp_path):
+    """Run ``body`` under a CPU ``jax.profiler`` trace written to
+    ``tmp_path``; returns the host events (plane, line, name, start, end)
+    of the profile."""
+    import jax
+    from jax.profiler import ProfileData
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        body()
+    finally:
+        jax.profiler.stop_trace()
+    paths = glob.glob(os.path.join(str(tmp_path), "**", "*.xplane.pb"),
+                      recursive=True)
+    assert len(paths) == 1
+    return [(plane.name, line.name, e.name, e.start_ns, e.end_ns)
+            for plane in ProfileData.from_file(paths[0]).planes
+            if not plane.name.startswith("/device:")
+            for line in plane.lines for e in line.events]
+
+
+def test_spans_reach_the_profiler_nested_on_one_line(tmp_path):
+    tr = Tracer()
+
+    def body():
+        with span("obs:outer", "compile") as sp:
+            sp["hit"] = True                   # dropped by the profiler
+            with span("obs:inner", "compile"):
+                pass
+        with activate(tr):                     # both sinks record
+            with span("obs:both", "compile", kernel="k") as sp:
+                sp["hit"] = False
+
+    ev = _profiled(body, tmp_path)
+    by = {}
+    for e in ev:
+        by.setdefault(e[2], []).append(e)
+    assert {n: len(by.get(n, [])) for n in
+            ("obs:outer", "obs:inner", "obs:both")} == \
+        {"obs:outer": 1, "obs:inner": 1, "obs:both": 1}
+    (outer,), (inner,) = by["obs:outer"], by["obs:inner"]
+    assert outer[:2] == inner[:2]               # one plane, one thread line
+    assert outer[3] <= inner[3] <= inner[4] <= outer[4]
+    assert by["obs:both"][0][3] >= outer[4]
+    assert [(s.name, s.args) for s in tr.spans()] == \
+        [("obs:both", {"kernel": "k", "hit": False})]
+    assert span("obs:after") is _NULL_SPAN      # the trace has stopped
+
+
+def _poly_program():
+    from repro.core.jit import jit_compile
+    return jit_compile(POLY1, SPEC, opts=OPTS).program
+
+
+LAUNCH_SPANS = ["launch:stage", "launch:h2d", "launch:wait", "launch:d2h"]
+
+
+def test_execute_books_launch_spans_in_order():
+    prog = _poly_program()
+    x = np.linspace(-1, 1, 300).astype(np.float32)
+    tr = Tracer()
+    with activate(tr):
+        out = ops.execute(prog, [x], interpret=True)
+    assert out[0].shape == x.shape
+    spans = sorted(tr.spans(), key=lambda s: s.ts_us)
+    assert [s.name for s in spans] == LAUNCH_SPANS
+    assert {s.cat for s in spans} == {"launch"}
+    for a, b in zip(spans, spans[1:]):
+        assert a.ts_us + a.dur_us <= b.ts_us
+    assert all(s.depth == 0 for s in spans)
+
+
+def test_session_enqueue_span_wraps_the_launch():
+    tr = Tracer()
+    x = np.linspace(-1, 1, 300).astype(np.float32)
+    with Session([Device("a", SPEC)], use_overlay_executor=True,
+                 tracer=tr) as sess:
+        fut = sess.compile(POLY1, OPTS, tenant="t")
+        ev = sess.enqueue(fut, x)
+        with activate(tr):
+            y = ev.wait()[0].read()
+    assert y.shape == x.shape
+    (enq,) = [s for s in tr.spans() if s.name == "session:enqueue"]
+    launch = sorted((s for s in tr.spans() if s.name in LAUNCH_SPANS),
+                    key=lambda s: s.ts_us)
+    assert [s.name for s in launch] == LAUNCH_SPANS
+    assert all(s.parent == enq.sid and s.track == enq.track for s in launch)
+    assert enq.ts_us <= launch[0].ts_us
+    assert launch[-1].ts_us + launch[-1].dur_us <= enq.ts_us + enq.dur_us
+    (read,) = [s for s in tr.spans() if s.name == "buffer:read"]
+    assert read.parent is None and read.ts_us >= enq.ts_us + enq.dur_us
+    # the modelled device slices stay in the tracer
+    assert any(s.track.startswith("dev:a/") for s in tr.spans())
+
+
+def test_modelled_spans_never_reach_the_profiler(tmp_path):
+    tr = Tracer()
+    x = np.linspace(-1, 1, 64).astype(np.float32)
+    with Session([Device("a", SPEC)], tracer=tr) as sess:
+        fut = sess.compile(POLY1, OPTS, tenant="t")
+        fut.result()
+
+        def body():
+            sess.enqueue(fut, x).wait()
+            with activate(tr):
+                modelled("obs:modelled", "dev:a/t", 0.0, 10.0)
+
+        ev = _profiled(body, tmp_path)
+    names = {e[2] for e in ev}
+    assert "session:enqueue" in names
+    dev = [s for s in tr.spans() if s.track.startswith("dev:")]
+    assert {s.name for s in dev} >= {"obs:modelled", "poly1"}
+    assert not names & {s.name for s in dev}
 
 
 def test_span_records_error_and_modelled_spans_are_roots():
