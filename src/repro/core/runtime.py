@@ -146,8 +146,13 @@ class Buffer:
         self.data = np.asarray(data, np.float32)
 
     def read(self) -> np.ndarray:
+        """The Buffer's words as a read-only view, with no copy: nothing
+        written through the result can change what the Buffer holds.  A
+        caller that needs to write takes its own copy."""
         with obs_trace.span("buffer:read", "launch"):
-            return self.data.copy()
+            view = self.data.view()
+            view.flags.writeable = False
+            return view
 
 
 class Context:

@@ -61,12 +61,15 @@ def _dispatch(op, lo: int, hi: int, args):
                     lambda: _dispatch(op, mid, hi, args))
 
 
-def _exec_kernel(instr_ref, imm_ref, x_ref, o_ref, regs_ref, *,
-                 n_in: int, n_out: int, n_instr: int, n_regs: int):
-    """Grid cell: execute the whole program on one work-item tile."""
+def _exec_kernel(instr_ref, imm_ref, *refs, n_in: int, n_out: int,
+                 n_instr: int, n_regs: int):
+    """Grid cell: execute the whole program on one work-item tile.  ``refs``
+    holds the n_in input tiles, then the output tile, then the register
+    file."""
+    x_refs, o_ref, regs_ref = refs[:n_in], refs[n_in], refs[n_in + 1]
     # preload inputs into the first n_in register slots (static unroll)
     for i in range(n_in):
-        regs_ref[i, :] = x_ref[i, :]
+        regs_ref[i, :] = x_refs[i][0, :]
 
     def body(k, carry):
         # the instruction table is flat (6*M,) int32: a 2-D (M, 6) SMEM
@@ -98,13 +101,15 @@ def _exec_kernel(instr_ref, imm_ref, x_ref, o_ref, regs_ref, *,
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "n_in", "n_out", "n_instr", "n_regs", "block", "interpret"))
-def overlay_execute(instrs, imms, x, *, n_in: int, n_out: int, n_instr: int,
+    "n_out", "n_instr", "n_regs", "block", "interpret"))
+def overlay_execute(instrs, imms, *xs, n_out: int, n_instr: int,
                     n_regs: int, block: int = 1024,
                     interpret: Optional[bool] = None):
-    """instrs: (6*n_instr,) i32; imms: (n_instr,) f32; x: (n_in, N) f32, N a
-    multiple of ``block`` → (n_out, N) f32."""
-    n = x.shape[1]
+    """instrs: (6*n_instr,) i32; imms: (n_instr,) f32; xs: the n_in inputs,
+    each (1, N) f32 with N a multiple of ``block``, as separate operands so
+    that each goes to the device as the caller holds it → (n_out, N) f32."""
+    n_in = len(xs)
+    n = xs[0].shape[1]
     grid = (n // block,)
     kernel = functools.partial(_exec_kernel, n_in=n_in, n_out=n_out,
                                n_instr=n_instr, n_regs=n_regs)
@@ -113,10 +118,10 @@ def overlay_execute(instrs, imms, x, *, n_in: int, n_out: int, n_instr: int,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=grid,
-            in_specs=[pl.BlockSpec((n_in, block), lambda i, *_: (0, i))],
+            in_specs=[pl.BlockSpec((1, block), lambda i, *_: (0, i))] * n_in,
             out_specs=pl.BlockSpec((n_out, block), lambda i, *_: (0, i)),
             scratch_shapes=[pltpu.VMEM((n_regs, block), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct((n_out, n), jnp.float32),
         interpret=interpret_mode(interpret),
-    )(instrs, imms, x)
+    )(instrs, imms, *xs)

@@ -90,40 +90,60 @@ def _pick_block(n: int, n_regs: int, n_in: int, n_out: int,
     return int(min(b, 4096))
 
 
+def _aligned(x, n_pad: int) -> bool:
+    """The executor can take ``x`` as it is: a C-contiguous float32 NumPy
+    array of exactly ``n_pad`` items, which reshapes to (1, n_pad) as a
+    view."""
+    return (isinstance(x, np.ndarray) and x.dtype == np.float32
+            and x.flags.c_contiguous and x.size == n_pad)
+
+
 def execute(program: OverlayProgram, inputs: Sequence, *,
             interpret: Optional[bool] = None, pad_to: int = 0,
             pad_regs: int = 0) -> List[np.ndarray]:
     """Run an OverlayProgram over flat work-item arrays via the Pallas
-    executor. Accepts any shaped arrays; work-items = flattened elements.
+    executor. Accepts any shaped arrays of one size; work-items = flattened
+    elements, and each output takes the first input's shape.
     ``pad_to``/``pad_regs`` pad the image to a shared signature.
 
+    Inputs that are C-contiguous float32 and a whole number of executor
+    blocks go to the device as they are, each as its own (1, n) operand:
+    no host copy.  Otherwise (a ragged size, another dtype, a strided
+    view) all of them are converted and padded into one float32
+    (n_in, n_pad) host array first; both cases run the same executable.
+
     The host path is four spans (``repro.obs.trace``): ``launch:stage``
-    (the inputs as one float32 (n_in, n_pad) array), ``launch:h2d`` (the
-    transfers to the device), ``launch:wait`` (the executor's result
-    ready) and ``launch:d2h`` (the output back on the host)."""
-    import jax.numpy as jnp
+    (the inputs' views and checks, and inside it ``launch:pad`` where the
+    host converts and pads), ``launch:h2d`` (the transfers to the device),
+    ``launch:wait`` (the executor's result ready) and ``launch:d2h`` (the
+    output back on the host, once; each output is a view of it)."""
+    import jax
 
     from repro.kernels.overlay_exec.kernel import overlay_execute
 
     instrs, imms, n_regs, n_out = build_image(program, pad_to=pad_to,
                                               pad_regs=pad_regs)
     with obs_trace.span("launch:stage", "launch"):
-        arrs = [np.asarray(x, np.float32) for x in inputs]
-        shape = arrs[0].shape
-        n = int(np.prod(shape)) if shape else 1
-        x = np.stack([a.ravel() for a in arrs])           # (n_in, N)
-        n_in = x.shape[0]
+        shape = np.shape(inputs[0])
+        n = int(np.prod(shape))
+        if any(np.size(x) != n for x in inputs):
+            raise ValueError("overlay inputs differ in size: "
+                             f"{[np.size(x) for x in inputs]}")
+        n_in = len(inputs)
         block = _pick_block(n, n_regs, n_in, n_out)
         n_pad = (n + block - 1) // block * block
-        if n_pad != n:
-            x = np.concatenate([x, np.zeros((n_in, n_pad - n), np.float32)],
-                               axis=1)
+        if all(_aligned(x, n_pad) for x in inputs):
+            xs = [x.reshape(1, n_pad) for x in inputs]
+        else:
+            with obs_trace.span("launch:pad", "launch"):
+                x = np.zeros((n_in, n_pad), np.float32)
+                for i, a in enumerate(inputs):
+                    x[i, :n] = np.ravel(a)
+                xs = [x[i:i + 1] for i in range(n_in)]
 
     with obs_trace.span("launch:h2d", "launch"):
-        d_instrs, d_imms, d_x = (jnp.asarray(instrs.ravel()),
-                                 jnp.asarray(imms), jnp.asarray(x))
-    out = overlay_execute(d_instrs, d_imms, d_x,
-                          n_in=n_in, n_out=n_out,
+        d_instrs, d_imms, *d_xs = jax.device_put([instrs.ravel(), imms, *xs])
+    out = overlay_execute(d_instrs, d_imms, *d_xs, n_out=n_out,
                           n_instr=int(instrs.shape[0]), n_regs=n_regs,
                           block=block, interpret=interpret)
     with obs_trace.span("launch:wait", "launch"):

@@ -34,7 +34,7 @@ def _overlay(interpret):
     # one NOP writing the immediate into the output register
     instrs = jnp.asarray([0, 1, 0, 0, 0, 0], jnp.int32)
     return overlay_execute(instrs, jnp.ones((1,), jnp.float32),
-                           jnp.zeros((1, 1024), jnp.float32), n_in=1,
+                           jnp.zeros((1, 1024), jnp.float32),
                            n_out=1, n_instr=1, n_regs=2,
                            interpret=interpret)
 

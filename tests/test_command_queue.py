@@ -133,6 +133,26 @@ def test_event_outputs_and_profile():
     assert q.profile()[0]["kernel"] == prog.compiled.name
 
 
+@pytest.mark.parametrize("source", ["host_array", "kernel_output"])
+def test_buffer_read_is_a_read_only_view(source):
+    """``Buffer.read`` copies nothing, yet nothing written through what it
+    returns reaches the Buffer."""
+    if source == "host_array":
+        buf = Buffer(X.copy())
+    else:
+        ctx = _ctx()
+        prog = ctx.build_program(BENCHMARKS["poly1"][0])
+        (buf,) = ctx.create_queue().enqueue_kernel(
+            prog.create_kernel().set_args(Buffer(X))).wait()
+    before = buf.data.copy()
+    got = buf.read()
+    assert np.shares_memory(got, buf.data)
+    with pytest.raises(ValueError, match="read-only"):
+        got[0] = 123.0
+    np.testing.assert_array_equal(buf.data, before)
+    np.testing.assert_array_equal(buf.read(), before)
+
+
 def test_barrier_orders_across_out_of_order_queue():
     ctx = _ctx()
     prog = ctx.build_program(BENCHMARKS["poly1"][0])

@@ -208,12 +208,15 @@ def test_execute_books_launch_spans_in_order():
     with activate(tr):
         out = ops.execute(prog, [x], interpret=True)
     assert out[0].shape == x.shape
-    spans = sorted(tr.spans(), key=lambda s: s.ts_us)
+    spans = sorted((s for s in tr.spans() if s.depth == 0),
+                   key=lambda s: s.ts_us)
     assert [s.name for s in spans] == LAUNCH_SPANS
-    assert {s.cat for s in spans} == {"launch"}
+    assert {s.cat for s in tr.spans()} == {"launch"}
     for a, b in zip(spans, spans[1:]):
         assert a.ts_us + a.dur_us <= b.ts_us
-    assert all(s.depth == 0 for s in spans)
+    # 300 items are not a whole number of executor blocks: the host pads
+    (pad,) = [s for s in tr.spans() if s.depth > 0]
+    assert pad.name == "launch:pad" and pad.parent == spans[0].sid
 
 
 def test_session_enqueue_span_wraps_the_launch():

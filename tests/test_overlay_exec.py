@@ -20,7 +20,11 @@ KERNELS = {
     "neg": (lambda a: -a + abs(a), 1),
     "three": (lambda a, b, c: a * b + b * c + a * c, 3),
     "multi_out": (lambda a, b: (a + b, a * b, a - b), 2),
+    "four": (lambda a, b, c, d: a * b + c * d - a, 4),
 }
+
+#: a kernel of each input count the launch tests cover
+BY_N_IN = {1: "poly", 2: "mad", 4: "four"}
 
 
 def _program(name):
@@ -49,6 +53,110 @@ def test_kernel_preserves_shape(shape):
     x = np.random.default_rng(0).standard_normal(shape).astype(np.float32)
     out = ops.execute(prog, [x])[0]
     assert out.shape == shape
+
+
+def _launch_inputs(n_in, n_items, layout, seed=3):
+    """n_in inputs of ``n_items`` work-items each: C-contiguous float32,
+    float64, or float32 strided views (every other item of a longer
+    array)."""
+    rng = np.random.default_rng(seed)
+    if layout == "strided":
+        return [rng.standard_normal(2 * n_items).astype(np.float32)[::2]
+                for _ in range(n_in)]
+    dtype = np.float64 if layout == "float64" else np.float32
+    return [rng.standard_normal(n_items).astype(dtype) for _ in range(n_in)]
+
+
+def _block(prog):
+    """The executor block ``ops.execute`` picks for ``prog``."""
+    _, _, n_regs, n_out = ops.build_image(prog)
+    return ops._pick_block(0, n_regs, len(prog.in_slots), n_out)
+
+
+@pytest.mark.parametrize("layout", ["f32_contiguous", "float64", "strided"])
+@pytest.mark.parametrize("size", ["aligned", "ragged"])
+@pytest.mark.parametrize("n_in", sorted(BY_N_IN))
+def test_launch_matches_oracle(n_in, size, layout):
+    """Every way inputs can reach the launch, aligned to the executor's
+    block (taken as they are) or not (converted and padded on the host),
+    agrees with the NumPy oracle."""
+    prog, _ = _program(BY_N_IN[n_in])
+    block = _block(prog)
+    n_items = block if size == "aligned" else block + 37
+    xs = _launch_inputs(n_in, n_items, layout)
+    want = ref.execute(prog, [np.asarray(x, np.float32) for x in xs])
+    got = ops.execute(prog, xs, interpret=True)
+    assert len(got) == len(want)
+    for w, g in zip(want, got):
+        assert g.shape == xs[0].shape and g.dtype == np.float32
+        np.testing.assert_allclose(g, w, rtol=RTOL, atol=ATOL)
+
+
+@pytest.mark.parametrize("size, layout, pads", [
+    ("aligned", "f32_contiguous", 0),
+    ("ragged", "f32_contiguous", 1),
+    ("aligned", "float64", 1),
+    ("aligned", "strided", 1),
+])
+def test_launch_pads_on_host_only_when_it_must(size, layout, pads):
+    """An aligned float32 launch opens no ``launch:pad`` span; a ragged,
+    float64 or strided one opens exactly one, inside ``launch:stage``."""
+    from repro.obs.trace import Tracer, activate
+    prog, n_in = _program("mad")
+    block = _block(prog)
+    xs = _launch_inputs(n_in, block if size == "aligned" else block - 5,
+                        layout)
+    tr = Tracer()
+    with activate(tr):
+        ops.execute(prog, xs, interpret=True)
+    spans = tr.spans()
+    pad = [s for s in spans if s.name == "launch:pad"]
+    assert len(pad) == pads
+    (stage,) = [s for s in spans if s.name == "launch:stage"]
+    assert all(s.parent == stage.sid for s in pad)
+
+
+def test_aligned_launch_hands_the_inputs_to_the_device_as_they_are(
+        monkeypatch):
+    """The arrays an aligned float32 launch transfers are the caller's
+    own memory, not host copies of it."""
+    import jax
+    prog, n_in = _program("mad")
+    xs = _launch_inputs(n_in, 2 * _block(prog), "f32_contiguous")
+    sent = []
+    put = jax.device_put
+    monkeypatch.setattr(jax, "device_put",
+                        lambda arrs, *a, **kw: sent.extend(arrs) or
+                        put(arrs, *a, **kw))
+    got = ops.execute(prog, xs, interpret=True)
+    want = ref.execute(prog, xs)
+    np.testing.assert_allclose(got[0], want[0], rtol=RTOL, atol=ATOL)
+    host = sent[-n_in:]
+    assert all(np.shares_memory(h, x) for h, x in zip(host, xs))
+
+
+def test_aligned_and_padded_launches_share_an_executable():
+    """A launch padded on the host runs the executable an aligned launch of
+    the same padded size compiled."""
+    from repro.kernels.overlay_exec.kernel import overlay_execute
+    prog, n_in = _program("mad")
+    block = _block(prog)
+    ops.execute(prog, _launch_inputs(n_in, 2 * block, "f32_contiguous"),
+                interpret=True)
+    n0 = overlay_execute._cache_size()
+    for layout in ("float64", "strided"):
+        ops.execute(prog, _launch_inputs(n_in, 2 * block, layout),
+                    interpret=True)
+    ops.execute(prog, _launch_inputs(n_in, 2 * block - 1, "f32_contiguous"),
+                interpret=True)
+    assert overlay_execute._cache_size() == n0
+
+
+def test_launch_rejects_inputs_of_different_sizes():
+    prog, _ = _program("mad")
+    with pytest.raises(ValueError, match="differ in size"):
+        ops.execute(prog, [np.zeros(256, np.float32),
+                           np.zeros(1, np.float32)], interpret=True)
 
 
 def test_padded_programs_share_signature():

@@ -74,7 +74,7 @@ def test_overlay_executor_longest_suite_program(one_chip):
 
     compiled = overlay_execute.lower(
         sds((instrs.size,), jnp.int32), sds(imms.shape, jnp.float32),
-        sds((n_in, N_ITEMS), jnp.float32), n_in=n_in, n_out=n_out,
+        *[sds((1, N_ITEMS), jnp.float32)] * n_in, n_out=n_out,
         n_instr=pad_to, n_regs=n_regs,
         block=ops._pick_block(N_ITEMS, n_regs, n_in, n_out),
         interpret=False).compile()
