@@ -3,10 +3,11 @@
 ``BENCHMARK.json`` names each cell's configuration and traffic.  The
 configuration is the file the benchmark lists for it; the traffic is
 ``chipbench/traffic/<traffic>.json``, whose ``runner`` names the module
-``chipbench.runners.<runner>`` that serves it; a per-layer metric
+``chipbench/runners/<runner>.py`` that serves it; a per-layer metric
 ``<name>`` is read by ``chipbench/metrics/<name>.py``.  Adding a cell, a
-traffic mix, a configuration or a metric is adding files: nothing here
-names one.
+traffic mix, a configuration, a runner or a metric is adding files:
+nothing here names one.  A configuration or traffic file may hold a
+``"cpu"`` object of smaller sizes for the CPU tests; no runner reads it.
 
 A runner's ``run(run)`` sets up, calls ``run.mark_window_start()``, serves
 the window, checks what it produced, and returns ``correct``,
@@ -15,7 +16,10 @@ it measured (``end_to_end``), the numbers compared with their limits
 (``checks``: name -> [value, limit]) and what the per-layer readers read
 (``readings``).  Where ``run.control`` is set, the numbers compared are
 the control's, put in the program's place: that run must come out not
-correct.  ``run.mark(phase)`` times the steps of set-up and check.
+correct.  ``run.mark(phase)`` times the steps of set-up and check.  A
+runner's ``control_readings(cell, seeds, seconds, require_chip)`` yields,
+for each seed, the number the cell compares for the program and for the
+control (``chipbench/control.py``).
 """
 
 from __future__ import annotations
@@ -59,6 +63,15 @@ def load_module(path: Path) -> ModuleType:
         s.loader.exec_module(mod)
         sys.modules[name] = mod
     return mod
+
+
+def runner(name: str, root: Path = ROOT) -> ModuleType:
+    """The runner ``chipbench/runners/<name>.py`` of the tree at ``root``:
+    the package's own module where ``root`` is this checkout, so that what
+    a test patches there is what runs; otherwise loaded from its file."""
+    if Path(root).resolve() == ROOT:
+        return importlib.import_module(f"chipbench.runners.{name}")
+    return load_module(Path(root) / "chipbench" / "runners" / f"{name}.py")
 
 
 def cell_spec(bench: dict, workload: str, root: Path = ROOT) -> dict:
@@ -150,9 +163,7 @@ def run_traffic(cell: dict, seed: int, seconds: float, trace: bool, *,
     devices = find_devices(cell["chips"], require_chip)
     run = Run(cell, seed, seconds, trace, devices, t_start, control)
     run.mark("start and devices")
-    runner = importlib.import_module(
-        f"chipbench.runners.{cell['traffic_data']['runner']}")
-    out = runner.run(run)
+    out = runner(cell["traffic_data"]["runner"], root).run(run)
     print(run.phase_line(), file=sys.stderr, flush=True)
     return run, out
 

@@ -274,3 +274,24 @@ def run(run) -> dict:
             step_counts=[counts.dense_decode_step(cell.c, cell.B, n,
                                                   run.chips)
                          for n in (trace.kv_lens if trace else [])]))
+
+
+def control_readings(cell, seeds, seconds, require_chip=True):
+    """Per seed, one whole wave through the timed path: the sampled
+    sequences' widest served-token gap (``program``) and that of the
+    float8 control's choices (``control``).  There is no window, so
+    ``seconds`` is not used."""
+    from chipbench import harness
+    devices = harness.find_devices(cell["chips"], require_chip)
+    run = harness.Run(cell, seeds[0], 0.0, False, devices,
+                      time.perf_counter())
+    dc = Cell(run)
+    for seed in seeds:
+        dc.set_up(seed)
+        prompts = dc.prompts(seed, 0)
+        waves = [dict(prompts=prompts, served=dc.wave(prompts)["served"])]
+        del dc.step
+        g = dc.gaps(dc.sample(waves, seed, run.traffic["check_sequences"]),
+                    lowp=True)
+        yield dict(seed=seed, program=g["served"], control=g["control"])
+        del dc.params

@@ -306,3 +306,19 @@ def run(run) -> dict:
                 suite.TEMPLATES[r["kernel"]].n_out, r["items"])
                 for r in done)),
         requests=reqs, pool=cell.pool)
+
+
+def control_readings(cell, seeds, seconds, require_chip=True):
+    """Per seed, a window of ``seconds`` at the cell's own load: the
+    sampled requests' widest relative error (``program``) and that of
+    the oracle in bfloat16 on them (``control``)."""
+    import ml_dtypes
+    from chipbench import harness
+    for seed in seeds:
+        _, out = harness.run_traffic(cell, seed, seconds, False,
+                                     t_start=time.perf_counter(),
+                                     require_chip=require_chip)
+        yield dict(seed=seed, program=out["checks"]["worst_rel_err"][0],
+                   control=control_err(out["requests"], out["pool"],
+                                       ml_dtypes.bfloat16),
+                   checked=sum("out" in r for r in out["requests"]))

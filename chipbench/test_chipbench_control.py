@@ -2,9 +2,9 @@
 below the configuration) must come out wrong, and so must a run whose
 timed path is broken underneath, once for each fault a cell can have.
 
-These run the harness on the CPU at small sizes, past its look for a chip.
-The limits are the configuration files' own, set from chip readings
-(PERF.md)."""
+These run the harness on the CPU, past its look for a chip, at the sizes
+of the configuration and traffic files' ``"cpu"`` blocks.  The limits are
+the configuration files' own, set from chip readings (PERF.md)."""
 
 import ml_dtypes
 import numpy as np
@@ -12,12 +12,7 @@ import pytest
 
 from chipbench import harness
 from chipbench.runners import dense_decode, overlay_session
-from chipbench.test_chipbench_harness import run_tiny, tiny_cell
-
-# big enough on the CPU that float8 rounding moves the served tokens
-SMALL_LM = dict(n_layers=4, d_model=256, n_heads=4, n_kv_heads=2,
-                head_dim=64, d_ff=512, vocab=2048)
-SMALL_WAVES = dict(batch=4, prompt=16, gen=16, cache_len=32)
+from chipbench.test_chipbench_harness import BENCH, run_tiny, tiny_cell
 
 
 @pytest.mark.parametrize("cell", ["suite_bulk", "suite_jit_churn"])
@@ -34,11 +29,10 @@ def test_overlay_control_fails_where_the_program_passes(cell):
 
 def test_decode_control_fails_where_the_program_passes():
     from chipbench import control
-    cell = tiny_cell("yi6b_decode")
-    cell["config_data"].update(SMALL_LM)
-    cell["traffic_data"].update(SMALL_WAVES)
+    cell = tiny_cell("yi6b_decode", "small")
     limit = cell["config_data"]["check"]["served_logit_gap_max"]
-    for r in control.decode_readings(cell, [1, 3], require_chip=False):
+    for r in control.readings(cell, [1, 3], cell["window_s"],
+                              require_chip=False):
         assert r["program"] <= limit < r["control"], r
 
 
@@ -126,25 +120,17 @@ def test_decode_fault_is_not_correct(monkeypatch, cell, fault, target):
     assert not run_tiny(cell)["correct"]
 
 
-@pytest.mark.parametrize("cell", ["suite_bulk", "suite_jit_churn",
-                                  "yi6b_decode"])
+@pytest.mark.parametrize("cell", [w["name"] for w in BENCH["workloads"]])
 def test_control_in_the_programs_place_is_not_correct(cell):
     """``--control 1``: the harness's own comparison, with the control's
     outputs in the program's place, reports not correct where the
-    program's run of the same seed is correct."""
-    c = tiny_cell(cell)
-    seconds = 0.5
-    if c["traffic_data"]["runner"] == "dense_decode":
-        # every slot of the small batch, so that as many served tokens are
-        # compared as the float8 control needs to show; a window shorter
-        # than one step, so that the wave compared is the same every time
-        c["config_data"].update(SMALL_LM)
-        c["traffic_data"].update(SMALL_WAVES, check_sequences=4)
-        seconds = 1e-3
-    runs = [harness.run_cell(cell, 3, seconds, False, require_chip=False,
-                             cell=c, control=control)
+    program's run of the same seed is correct.  Sizes and window are the
+    files' ``cpu.small`` over ``cpu.tiny``."""
+    c = tiny_cell(cell, "small")
+    runs = [harness.run_cell(cell, 3, c["window_s"], False,
+                             require_chip=False, cell=c, control=control)
             for control in (False, True)]
-    assert runs[0]["correct"] and not runs[1]["correct"]
+    assert runs[0]["correct"] and not runs[1]["correct"], [r["checks"] for r in runs]
     assert any(v["value"] > v["limit"] for v in runs[1]["checks"].values())
 
 

@@ -77,6 +77,45 @@ def test_leaves_and_op_names():
                                                    "copy.3": 10e-9})
 
 
+def test_scope_is_optional_and_left_out_of_the_reductions():
+    plain = Event(D0, OPS, "fusion.2", 0, 10)
+    scoped = Event(D0, OPS, "fusion.7", 10, 30, "jit(step)/mlp/dot_general:")
+    assert plain.scope == "" and scoped.scope.split("/")[1] == "mlp"
+    ev = [Event("/host:CPU", "python", tracefile.WINDOW, 0, 40), plain,
+          scoped]
+    assert tracefile.busy_ns(ev, D0, 0, 40) == pytest.approx(30)
+    assert tracefile.total_ns(e for e in ev if "/mlp/" in e.scope) == 20
+    assert dict(tracefile.breakdown(ev)["device_ops"]) == pytest.approx(
+        {"fusion.7": 20e-9, "fusion.2": 10e-9})
+
+
+def test_op_scopes_read_the_metadata_stat(tmp_path):
+    """A device operation's scope is its metadata's ``tf_op`` stat, held
+    as a string or as a reference to a stat name; host planes and other
+    stats are left out."""
+    space = tracefile._xspace()()
+    for pname in (D0, "/host:CPU"):
+        plane = space.planes.add(name=pname.encode())
+        for key, name in [(1, b"tf_op"), (2, b"hlo_op"),
+                          (3, b"jit(step)/attn/dot_general:")]:
+            plane.stat_metadata.add(key=key).value.name = name
+        op = plane.event_metadata.add(key=7).value
+        op.name, op.display_name = b"%fusion.7 = bf16[8] fusion(...)", \
+            b"fusion.7"
+        op.stats.add(metadata_id=2, str_value=b"fusion.7")
+        op.stats.add(metadata_id=1, str_value=b"jit(step)/mlp/mul:")
+        ref = plane.event_metadata.add(key=8).value
+        ref.name = b"copy.3"
+        ref.stats.add(metadata_id=1, ref_value=3)
+        plane.event_metadata.add(key=9).value.name = b"convert.1"
+    path = tmp_path / "t.xplane.pb"
+    path.write_bytes(space.SerializeToString())
+    assert tracefile.op_scopes(str(path)) == {
+        (D0, "%fusion.7 = bf16[8] fusion(...)"): "jit(step)/mlp/mul:",
+        (D0, "fusion.7"): "jit(step)/mlp/mul:",
+        (D0, "copy.3"): "jit(step)/attn/dot_general:"}
+
+
 def test_window_must_be_marked_once():
     with pytest.raises(RuntimeError):
         tracefile.window([e for e in _trace() if e.name != tracefile.WINDOW])

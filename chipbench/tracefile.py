@@ -2,16 +2,18 @@
 share, kernel time, collective time and the breakdown of a result line.
 
 A trace is reduced to a flat list of :class:`Event` (plane, line, name,
-start, end in ns on the profiler's one clock), so the reductions below can
-be checked on a hand-built list.  Device planes are the ``/device:TPU:n``
-planes; an operation's interval comes from their ``XLA Ops`` line, a
-jitted program's from ``XLA Modules``.  The harness marks the traced window
+start, end in ns on the profiler's one clock, and a device operation's
+scope), so the reductions below can be checked on a hand-built list.
+Device planes are the ``/device:TPU:n`` planes; an operation's interval
+comes from their ``XLA Ops`` line, a jitted program's from ``XLA
+Modules``.  The harness marks the traced window
 with a host annotation named :data:`WINDOW`.
 """
 
 from __future__ import annotations
 
 import contextlib
+import functools
 import glob
 import os
 import re
@@ -23,6 +25,10 @@ from typing import Dict, Iterable, List, NamedTuple, Tuple
 WINDOW = "chipbench.window"
 OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
+# the stat of a device operation's metadata that holds its HLO ``op_name``:
+# the jitted function and the ``jax.named_scope`` names it was traced in,
+# as ``jit(f)/attn/dot_general:`` on a TPU v5e
+SCOPE_STAT = "tf_op"
 COLLECTIVE = \
     r"all-reduce|all-gather|reduce-scatter|all-to-all|collective-permute"
 
@@ -33,6 +39,7 @@ class Event(NamedTuple):
     name: str
     start_ns: float
     end_ns: float
+    scope: str = ""
 
 
 class Capture:
@@ -83,12 +90,85 @@ def load(directory: str) -> List[Event]:
     if len(paths) != 1:
         raise RuntimeError(f"expected one profile under {directory}, "
                            f"found {len(paths)}")
+    scopes = op_scopes(paths[0])
     out: List[Event] = []
     for plane in ProfileData.from_file(paths[0]).planes:
         for line in plane.lines:
             for e in line.events:
                 out.append(Event(plane.name, line.name, e.name,
-                                 float(e.start_ns), float(e.end_ns)))
+                                 float(e.start_ns), float(e.end_ns),
+                                 scopes.get((plane.name, e.name), "")))
+    return out
+
+
+@functools.lru_cache(maxsize=None)
+def _xspace():
+    """The message class of the part of the profiler's ``XSpace``
+    (``tsl/profiler/protobuf/xplane.proto``, same field numbers) that holds
+    each operation's metadata, whose stats ``ProfileData`` does not show.
+    A map is read in its wire form, repeated key/value entries; strings
+    are read as bytes; every other field is skipped unparsed."""
+    from google.protobuf import (descriptor_pb2, descriptor_pool,
+                                 message_factory)
+    F = descriptor_pb2.FieldDescriptorProto
+    fd = descriptor_pb2.FileDescriptorProto(name="chipbench_xspace.proto",
+                                            package="chipbench_xspace")
+    for name, fields in {
+            "Stat": [("metadata_id", 1, "int64"), ("str_value", 5, "bytes"),
+                     ("ref_value", 7, "uint64")],
+            "EventMetadata": [("name", 2, "bytes"),
+                              ("display_name", 4, "bytes"),
+                              ("stats", 5, "*Stat")],
+            "StatMetadata": [("name", 2, "bytes")],
+            "EventMetadataEntry": [("key", 1, "int64"),
+                                   ("value", 2, "EventMetadata")],
+            "StatMetadataEntry": [("key", 1, "int64"),
+                                  ("value", 2, "StatMetadata")],
+            "Plane": [("name", 2, "bytes"),
+                      ("event_metadata", 4, "*EventMetadataEntry"),
+                      ("stat_metadata", 5, "*StatMetadataEntry")],
+            "Space": [("planes", 1, "*Plane")]}.items():
+        m = fd.message_type.add(name=name)
+        for fname, number, kind in fields:
+            f = m.field.add(name=fname, number=number)
+            f.label = F.LABEL_REPEATED if kind[0] == "*" else \
+                F.LABEL_OPTIONAL
+            kind = kind.lstrip("*")
+            if kind in ("int64", "uint64", "bytes"):
+                f.type = getattr(F, f"TYPE_{kind.upper()}")
+            else:
+                f.type, f.type_name = F.TYPE_MESSAGE, \
+                    f".chipbench_xspace.{kind}"
+    pool = descriptor_pool.DescriptorPool()
+    pool.Add(fd)
+    return message_factory.GetMessageClass(
+        pool.FindMessageTypeByName("chipbench_xspace.Space"))
+
+
+def op_scopes(path: str) -> Dict[Tuple[str, str], str]:
+    """(device plane, operation name) -> the operation's
+    :data:`SCOPE_STAT`, read from the operations' metadata in the
+    ``.xplane.pb`` profile at ``path``; an operation keyed by its name and
+    by its display name."""
+    space = _xspace()()
+    with open(path, "rb") as fh:
+        space.ParseFromString(fh.read())
+    out: Dict[Tuple[str, str], str] = {}
+    for plane in space.planes:
+        pname = plane.name.decode(errors="replace")
+        if not pname.startswith("/device:"):
+            continue
+        stat_names = {e.key: e.value.name for e in plane.stat_metadata}
+        for entry in plane.event_metadata:
+            meta = entry.value
+            for st in meta.stats:
+                if stat_names.get(st.metadata_id) != SCOPE_STAT.encode():
+                    continue
+                scope = (st.str_value or stat_names.get(st.ref_value, b"")) \
+                    .decode(errors="replace")
+                for n in (meta.name, meta.display_name):
+                    if n:
+                        out[(pname, n.decode(errors="replace"))] = scope
     return out
 
 
