@@ -66,7 +66,7 @@ def reduced_config(cfg: ArchConfig) -> ArchConfig:
     """CPU-smoke-test sized variant of the same family: tiny depth/width,
     few experts, small vocab — exercises every code path of the family."""
     kw = dict(
-        n_layers=2 if cfg.attn_every == 0 else 4,
+        n_layers=2,
         d_model=64,
         n_heads=4, n_kv_heads=2 if cfg.n_kv_heads < cfg.n_heads else 4,
         d_ff=128, vocab=256, head_dim=16,
@@ -75,8 +75,11 @@ def reduced_config(cfg: ArchConfig) -> ArchConfig:
         kw.update(n_experts=4, top_k=2, moe_d_ff=64)
     if cfg.family in ("ssm", "hybrid"):
         kw.update(ssm_state=16, ssm_head_dim=16, ssm_chunk=8)
-    if cfg.attn_every:
-        kw.update(attn_every=2)
+    if cfg.family == "hybrid":
+        # four sites over six layers: each of the two blocks, with its
+        # adapters, runs twice; heads span the concat width 2·d_model
+        kw.update(n_layers=6, hybrid_layer_ids=(1, 2, 4, 5), head_dim=32,
+                  adapter_rank=8, attn_scale=16 ** -0.5)
     if cfg.enc_layers:
         kw.update(enc_layers=2)
     if cfg.window:

@@ -132,13 +132,17 @@ def _bilin_scalar(cc, k1, k2, g1, g2, L, G):
 
 
 def _small_cfgs(cfg):
-    """Two reduced-depth clones for the linear cost model.  zamba2's shared
-    attention fires every attn_every layers, so depth steps by that period
-    to keep one invocation per unit."""
+    """Two reduced-depth clones for the linear cost model.  A hybrid keeps
+    its first layers and the sites among them: the clones end at the
+    second and fourth site, so they differ by two sites and the layers
+    between them, the whole model's ratio of sites to layers."""
     import dataclasses as dc
-    k1 = cfg.attn_every if cfg.attn_every else 1
-    k2 = 2 * k1
+    ids = cfg.hybrid_layer_ids
+    k1, k2 = (ids[1], ids[3]) if ids else (1, 2)
     kw1, kw2 = {"n_layers": k1}, {"n_layers": k2}
+    if ids:
+        kw1["hybrid_layer_ids"] = tuple(i for i in ids if i < k1)
+        kw2["hybrid_layer_ids"] = tuple(i for i in ids if i < k2)
     if cfg.enc_layers:
         kw1["enc_layers"] = k1
         kw2["enc_layers"] = k2

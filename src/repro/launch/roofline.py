@@ -154,7 +154,7 @@ def attention_flops(cfg, seq: int, batch: int, kind: str) -> float:
     if cfg.family == "ssm":
         return 0.0
     layers = cfg.n_layers if cfg.family != "hybrid" else \
-        (cfg.n_layers + cfg.attn_every - 1) // cfg.attn_every
+        len(cfg.hybrid_layer_ids)
     hq, hd = cfg.n_heads, cfg.hd
     if kind == "decode":
         # one query against the whole cache: QK^T + PV

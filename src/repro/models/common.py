@@ -5,7 +5,8 @@ Every assigned architecture is an ``ArchConfig``; families:
             internvl backbone)
   moe     — mixture-of-experts transformer (mixtral, qwen3-moe)
   ssm     — Mamba2 / SSD (attention-free)
-  hybrid  — Mamba2 backbone + shared attention blocks (zamba2)
+  hybrid  — Mamba2 backbone + shared transformer blocks used in turn at
+            fixed sites, each with per-site adapters (zamba2)
   audio   — whisper encoder-decoder (conv frontend stubbed)
   vlm     — internvl (ViT frontend stubbed; backbone = dense)
 """
@@ -13,7 +14,7 @@ Every assigned architecture is an ``ArchConfig``; families:
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Dict, Optional
+from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -45,8 +46,15 @@ class ArchConfig:
     ssm_expand: int = 2
     ssm_chunk: int = 256
     conv_width: int = 4
-    # hybrid
-    attn_every: int = 0               # shared attn block period (zamba2)
+    ssm_groups: int = 1               # B/C groups; head h uses h // (H/G)
+    conv_bias: bool = False
+    # hybrid (zamba2): the layers whose Mamba input gets a shared block's
+    # output; site k runs shared block k % n_shared_blocks with its own
+    # rank-``adapter_rank`` MLP adapter and output linear
+    hybrid_layer_ids: Tuple[int, ...] = ()
+    n_shared_blocks: int = 0
+    adapter_rank: int = 0
+    attn_scale: Optional[float] = None    # None → hd^-0.5
     # attention variants
     window: Optional[int] = None      # sliding-window attention (mixtral)
     # enc-dec (whisper)
@@ -72,10 +80,42 @@ class ArchConfig:
         """Can this arch run long_500k (sub-quadratic token mixing)?"""
         return self.family in ("ssm", "hybrid") or self.window is not None
 
+    def mamba_layer_params(self) -> int:
+        """One Mamba2 layer with its input norm: in_proj to (z, xBC, dt),
+        the depthwise conv over xBC, A_log, D, dt_bias, the gated norm and
+        out_proj."""
+        d = self.d_model
+        di = self.ssm_expand * d
+        h = di // self.ssm_head_dim
+        conv = di + 2 * self.ssm_groups * self.ssm_state
+        return (d * (di + conv + h) + conv * self.conv_width
+                + (conv if self.conv_bias else 0) + 3 * h + di + di * d + d)
+
+    def shared_block_params(self) -> int:
+        """One zamba2 shared block: the norm over concat(x, embeddings),
+        attention from 2·d to d, the MLP's norm, gate-up and down."""
+        d, ff, hd, hq, hkv = (self.d_model, self.d_ff, self.hd,
+                              self.n_heads, self.n_kv_heads)
+        attn = 2 * d * hd * (hq + 2 * hkv) + hq * hd * d
+        return 2 * d + attn + d + 2 * d * ff + ff * d
+
+    def site_params(self) -> int:
+        """One hybrid site's own weights: the MLP adapter and the linear."""
+        d = self.d_model
+        return d * self.adapter_rank + self.adapter_rank * 2 * self.d_ff \
+            + d * d
+
     def param_count(self) -> int:
         """Total parameters N (for 6·N·D roofline bookkeeping)."""
         d, ff, v, L = self.d_model, self.d_ff, self.vocab, self.n_layers
         hd, hq, hkv = self.hd, self.n_heads, self.n_kv_heads
+        if self.family == "ssm":
+            return int(L * self.mamba_layer_params() + 2 * v * d + d)
+        if self.family == "hybrid":       # embeddings tied to the head
+            return int(L * self.mamba_layer_params()
+                       + self.n_shared_blocks * self.shared_block_params()
+                       + len(self.hybrid_layer_ids) * self.site_params()
+                       + v * d + d)
         attn = d * hd * (hq + 2 * hkv) + hq * hd * d
         if self.activation == "swiglu":
             mlp = 3 * d * ff
@@ -84,20 +124,7 @@ class ArchConfig:
         if self.family in ("moe",):
             mlp = self.n_experts * 3 * d * self.moe_d_ff + d * self.n_experts
         per_layer = attn + mlp + 2 * d
-        if self.family == "ssm":
-            di = self.ssm_expand * d
-            per_layer = (d * (2 * di + 2 * self.ssm_state +
-                              di // self.ssm_head_dim)
-                         + di * self.conv_width + di * d + 2 * d)
-        if self.family == "hybrid":
-            di = self.ssm_expand * d
-            ssm_l = (d * (2 * di + 2 * self.ssm_state +
-                          di // self.ssm_head_dim)
-                     + di * self.conv_width + di * d + 2 * d)
-            per_layer = ssm_l   # plus one shared attn block added below
         total = L * per_layer + v * d * 2   # tied-off embed + lm head
-        if self.family == "hybrid":
-            total += attn + 3 * d * ff + 2 * d
         if self.family == "audio":
             total += self.enc_layers * (attn + mlp + 2 * d)
             total += L * (attn + d * hd * (hq + 2 * hkv) // 1)  # cross-attn

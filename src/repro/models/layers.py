@@ -49,13 +49,17 @@ def apply_rope(x, pos, theta: float):
 
 # --------------------------------------------------------------- attention
 
-def init_attention(key, cfg: ArchConfig) -> Dict[str, Any]:
+def init_attention(key, cfg: ArchConfig,
+                   d_in: Optional[int] = None) -> Dict[str, Any]:
+    """Projections from width ``d_in`` (default d_model) to the heads and
+    from the heads back to d_model."""
     d, hd, hq, hkv = cfg.d_model, cfg.hd, cfg.n_heads, cfg.n_kv_heads
+    d_in = d_in or d
     ks = jax.random.split(key, 4)
     p = {
-        "wq": dense_init(ks[0], (d, hq * hd), dtype=cfg.dtype),
-        "wk": dense_init(ks[1], (d, hkv * hd), dtype=cfg.dtype),
-        "wv": dense_init(ks[2], (d, hkv * hd), dtype=cfg.dtype),
+        "wq": dense_init(ks[0], (d_in, hq * hd), dtype=cfg.dtype),
+        "wk": dense_init(ks[1], (d_in, hkv * hd), dtype=cfg.dtype),
+        "wv": dense_init(ks[2], (d_in, hkv * hd), dtype=cfg.dtype),
         "wo": dense_init(ks[3], (hq * hd, d), dtype=cfg.dtype),
     }
     if cfg.qk_norm:
@@ -93,7 +97,8 @@ def attention(p, x, cfg: ArchConfig, *, pos, kv: Optional[Tuple] = None,
         q = apply_rope(q, pos, cfg.rope_theta)
         k = apply_rope(k, pos, cfg.rope_theta)
     out = fa_ops.attention(q, k, v, causal=causal and memory is None,
-                           window=cfg.window, impl=attn_impl)
+                           window=cfg.window, scale=cfg.attn_scale,
+                           impl=attn_impl)
     return _merge_heads(out) @ p["wo"]
 
 
@@ -120,7 +125,8 @@ def attention_decode(p, x, cache_k, cache_v, cur_pos, cfg: ArchConfig,
     # causal w.r.t. aligned ends; for a mid-cache write we mask explicitly.
     kf = cache_k.astype(jnp.float32)
     vf = cache_v.astype(jnp.float32)
-    qf = q.astype(jnp.float32) * (hd ** -0.5)
+    scale = hd ** -0.5 if cfg.attn_scale is None else cfg.attn_scale
+    qf = q.astype(jnp.float32) * scale
     b = q.shape[0]
     group = hq // hkv
     qg = qf.reshape(b, hkv, group, 1, hd)
@@ -147,6 +153,30 @@ def init_mlp(key, cfg: ArchConfig, d_ff: Optional[int] = None) -> Dict:
                 "w_down": dense_init(ks[2], (ff, d), dtype=cfg.dtype)}
     return {"w_up": dense_init(ks[0], (d, ff), dtype=cfg.dtype),
             "w_down": dense_init(ks[1], (ff, d), dtype=cfg.dtype)}
+
+
+def init_adapted_mlp(key, cfg: ArchConfig) -> Dict:
+    """A gated MLP whose gate and up projections are one matrix."""
+    d, ff = cfg.d_model, cfg.d_ff
+    k1, k2 = jax.random.split(key)
+    return {"w_gate_up": dense_init(k1, (d, 2 * ff), dtype=cfg.dtype),
+            "w_down": dense_init(k2, (ff, d), dtype=cfg.dtype)}
+
+
+def init_adapter(key, cfg: ArchConfig) -> Dict:
+    """A rank-``adapter_rank`` adapter of ``w_gate_up`` (LoRA)."""
+    d, ff, r = cfg.d_model, cfg.d_ff, cfg.adapter_rank
+    k1, k2 = jax.random.split(key)
+    return {"lora_a": dense_init(k1, (d, r), dtype=cfg.dtype),
+            "lora_b": dense_init(k2, (r, 2 * ff), dtype=cfg.dtype)}
+
+
+def adapted_mlp(p, adapter, x):
+    """gelu(g)·u, where [g, u] = x·W_gate_up + (x·A)·B with the caller's
+    adapter (A, B), then W_down."""
+    gu = x @ p["w_gate_up"] + (x @ adapter["lora_a"]) @ adapter["lora_b"]
+    g, u = jnp.split(gu, 2, axis=-1)
+    return overlay_ops.gated_gelu(g, u) @ p["w_down"]
 
 
 def mlp(p, x, cfg: ArchConfig):

@@ -3,6 +3,10 @@ of zamba2.  Chunked matmul formulation (Dao & Gu 2024): intra-chunk terms are
 MXU-friendly batched matmuls; inter-chunk state is a short scan over chunks.
 Decode carries an explicit (heads, head_dim, state) recurrence — O(1) per
 token, which is what makes long_500k decode linear.
+
+B and C come in ``cfg.ssm_groups`` groups of ``ssm_state``; the H heads are
+split into as many runs of H/G, and head h reads group h // (H/G).  The
+gated output norm is an RMSNorm over each group's d_inner/G channels.
 """
 
 from __future__ import annotations
@@ -29,27 +33,33 @@ def ssm_dims(cfg: ArchConfig) -> Tuple[int, int, int]:
 def init_mamba_block(key, cfg: ArchConfig) -> Dict[str, Any]:
     d = cfg.d_model
     di, h, n = ssm_dims(cfg)
+    conv = di + 2 * cfg.ssm_groups * n
     ks = jax.random.split(key, 4)
-    return {
-        "in_proj": dense_init(ks[0], (d, 2 * di + 2 * n + h), dtype=cfg.dtype),
-        "conv_w": dense_init(ks[1], (cfg.conv_width, di + 2 * n),
-                             dtype=cfg.dtype),
+    p = {
+        "in_proj": dense_init(ks[0], (d, di + conv + h), dtype=cfg.dtype),
+        "conv_w": dense_init(ks[1], (cfg.conv_width, conv), dtype=cfg.dtype),
         "A_log": jnp.zeros((h,), jnp.float32),
         "D": jnp.ones((h,), jnp.float32),
         "dt_bias": jnp.zeros((h,), jnp.float32),
         "norm": jnp.ones((di,), cfg.dtype),
         "out_proj": dense_init(ks[2], (di, d), dtype=cfg.dtype),
     }
+    if cfg.conv_bias:
+        p["conv_b"] = jnp.zeros((conv,), cfg.dtype)
+    return p
 
 
 def mamba_specs(cfg: ArchConfig, multi_pod: bool = False) -> Dict[str, Any]:
-    return {
+    sp = {
         "in_proj": P(None, "model"),
         "conv_w": P(None, "model"),
         "A_log": P("model"), "D": P("model"), "dt_bias": P("model"),
         "norm": P("model"),
         "out_proj": P("model", None),
     }
+    if cfg.conv_bias:
+        sp["conv_b"] = P("model")
+    return sp
 
 
 def _causal_conv(x, w):
@@ -77,105 +87,139 @@ def ssd_chunked(xh, dt, A, Bm, Cm, chunk: int, compute_dtype=jnp.float32):
     """SSD in chunked matmul form.
 
     xh: (B, S, H, Pd) head inputs; dt: (B, S, H) discretisation steps;
-    A: (H,) negative decay rates; Bm, Cm: (B, S, N).
-    Returns (B, S, H, Pd) in f32.
+    A: (H,) negative decay rates; Bm, Cm: (B, S, G, N), head h reading
+    group h // (H/G).  Returns (B, S, H, Pd) in f32 and the final state
+    (B, H, Pd, N).
 
     compute_dtype: dtype of the large intra-chunk tensors (Lmat, xdt, B, C).
     Decay exponentials and the inter-chunk state scan stay f32 for
     stability; bf16 here halves the memory-roofline term (§Perf iteration).
     """
     b, s, h, pd = xh.shape
-    n = Bm.shape[-1]
+    g, n = Bm.shape[-2:]
+    e = h // g                                                 # heads/group
     c = s // chunk
     cl = chunk
 
-    x_ = xh.reshape(b, c, cl, h, pd).astype(compute_dtype)
-    dt_ = dt.reshape(b, c, cl, h)                              # f32
-    B_ = Bm.reshape(b, c, cl, n).astype(compute_dtype)
-    C_ = Cm.reshape(b, c, cl, n).astype(compute_dtype)
-    dA = (dt_ * A[None, None, None, :]).transpose(0, 3, 1, 2)  # (b,h,c,l) f32
-    xdt = x_ * dt_[..., None].astype(compute_dtype)            # (b,c,l,h,p)
+    x_ = xh.reshape(b, c, cl, g, e, pd).astype(compute_dtype)
+    dt_ = dt.reshape(b, c, cl, g, e)                           # f32
+    B_ = Bm.reshape(b, c, cl, g, n).astype(compute_dtype)
+    C_ = Cm.reshape(b, c, cl, g, n).astype(compute_dtype)
+    dA = (dt_ * A.reshape(g, e)).transpose(0, 3, 4, 1, 2)      # (b,g,e,c,l)
+    xdt = x_ * dt_[..., None].astype(compute_dtype)            # (b,c,l,g,e,p)
 
     # intra-chunk (diagonal blocks)
-    Lmat = jnp.exp(_segsum(dA)).astype(compute_dtype)          # (b,h,c,l,l)
-    y_diag = jnp.einsum("bcln,bcsn,bhcls,bcshp->bclhp", C_, B_, Lmat, xdt,
-                        preferred_element_type=jnp.float32)
+    Lmat = jnp.exp(_segsum(dA)).astype(compute_dtype)          # (b,g,e,c,l,l)
+    y_diag = jnp.einsum("bclgn,bcsgn,bgecls,bcsgep->bclgep", C_, B_, Lmat,
+                        xdt, preferred_element_type=jnp.float32)
 
     # chunk-final states
-    dA_cum = jnp.cumsum(dA, axis=-1)                           # (b,h,c,l)
-    decay_states = jnp.exp(dA_cum[..., -1:] - dA_cum)          # (b,h,c,l)
-    states = jnp.einsum("bcln,bhcl,bclhp->bchpn", B_,
+    dA_cum = jnp.cumsum(dA, axis=-1)                           # (b,g,e,c,l)
+    decay_states = jnp.exp(dA_cum[..., -1:] - dA_cum)          # (b,g,e,c,l)
+    states = jnp.einsum("bclgn,bgecl,bclgep->bcgepn", B_,
                         decay_states.astype(compute_dtype), xdt,
                         preferred_element_type=jnp.float32)
 
     # inter-chunk recurrence
-    chunk_decay = jnp.exp(dA_cum[..., -1])                     # (b,h,c)
+    chunk_decay = jnp.exp(dA_cum[..., -1])                     # (b,g,e,c)
 
     def scan_fn(prev, inp):
-        st, dec = inp                                          # (b,h,p,n),(b,h)
+        st, dec = inp                                  # (b,g,e,p,n),(b,g,e)
         new = prev * dec[..., None, None] + st
         return new, prev
 
-    states_t = states.transpose(1, 0, 2, 3, 4)                 # (c,b,h,p,n)
-    decay_t = chunk_decay.transpose(2, 0, 1)                   # (c,b,h)
+    states_t = states.transpose(1, 0, 2, 3, 4, 5)              # (c,b,g,e,p,n)
+    decay_t = chunk_decay.transpose(3, 0, 1, 2)                # (c,b,g,e)
     init = jnp.zeros_like(states_t[0])
     final_state, prev_states = lax.scan(scan_fn, init, (states_t, decay_t))
-    prev_states = prev_states.transpose(1, 2, 0, 3, 4)         # (b,h,c,p,n)
+    prev_states = prev_states.transpose(1, 2, 3, 0, 4, 5)      # (b,g,e,c,p,n)
 
-    state_decay = jnp.exp(dA_cum)                              # (b,h,c,l)
-    y_off = jnp.einsum("bcln,bhcpn,bhcl->bclhp", C_,
+    state_decay = jnp.exp(dA_cum)                              # (b,g,e,c,l)
+    y_off = jnp.einsum("bclgn,bgecpn,bgecl->bclgep", C_,
                        prev_states.astype(compute_dtype),
                        state_decay.astype(compute_dtype),
                        preferred_element_type=jnp.float32)
 
     y = (y_diag + y_off).reshape(b, s, h, pd)
-    return y, final_state
+    return y, final_state.reshape(b, h, pd, n)
+
+
+def split_xbc(xBC, cfg: ArchConfig):
+    """The conv's output → (x (…, d_inner), B and C (…, G, N))."""
+    di, _, n = ssm_dims(cfg)
+    gn = cfg.ssm_groups * n
+    xs, Bm, Cm = jnp.split(xBC, [di, di + gn], axis=-1)
+    shape = (*xBC.shape[:-1], cfg.ssm_groups, n)
+    return xs, Bm.reshape(shape), Cm.reshape(shape)
+
+
+def ssm_step(state, xh, dt, A, Bm, Cm):
+    """One position of the recurrence: state ← state·exp(dt·A) + dt·x ⊗ B,
+    read out through C.  state: (B, H, Pd, N) f32; xh: (B, H, Pd);
+    dt: (B, H) f32; Bm, Cm: (B, G, N).  Returns (y (B, H, Pd), state).
+    B and C are repeated over their heads rather than the state split by
+    group: a split of the heads' axis would relayout the whole state."""
+    e = state.shape[1] // Bm.shape[1]
+    Bh = jnp.repeat(Bm.astype(jnp.float32), e, axis=1)         # (B, H, N)
+    Ch = jnp.repeat(Cm.astype(jnp.float32), e, axis=1)
+    dA = jnp.exp(dt * A[None, :])[..., None, None]
+    xdt = xh.astype(jnp.float32) * dt[..., None]
+    new = state * dA + jnp.einsum("bhp,bhn->bhpn", xdt, Bh)
+    return jnp.einsum("bhpn,bhn->bhp", new, Ch), new
+
+
+def gated_norm(y, z, w, cfg: ArchConfig):
+    """RMSNorm(y·silu(z)) over each of the G groups of channels."""
+    y = overlay_ops.ssm_gate(y, z)
+    g = cfg.ssm_groups
+    # one group keeps the unreshaped norm: through the (…, 1, di) reshape
+    # XLA reduces in another order, and mamba2-370m's bfloat16 logits
+    # move by up to 0.07 on the CPU
+    if g == 1:
+        return L.rmsnorm(y, w, cfg.norm_eps)
+    yg = y.reshape(*y.shape[:-1], g, y.shape[-1] // g)
+    return L.rmsnorm(yg, w.reshape(g, -1), cfg.norm_eps).reshape(y.shape)
 
 
 def mamba_block(p, x, cfg: ArchConfig,
                 conv_state=None, ssm_state=None, decode: bool = False,
                 ssd_dtype=jnp.float32):
     """Full Mamba2 block. Train: (B,S,d)→(B,S,d). Decode: one step with
-    carried (conv_state (B,K-1,di+2n), ssm_state (B,H,Pd,N))."""
+    carried (conv_state (B,K-1,di+2Gn), ssm_state (B,H,Pd,N))."""
     di, h, n = ssm_dims(cfg)
     pd = cfg.ssm_head_dim
-    zxbcdt = x @ p["in_proj"]                    # (B,S, 2di+2n+h)
-    z, xBC, dt = jnp.split(zxbcdt, [di, 2 * di + 2 * n], axis=-1)
+    zxbcdt = x @ p["in_proj"]                    # (B,S, 2di+2Gn+h)
+    z, xBC, dt = jnp.split(zxbcdt, [di, zxbcdt.shape[-1] - h], axis=-1)
 
     if not decode:
         xBC = _causal_conv(xBC, p["conv_w"])
         new_conv = None
     else:
-        prev = conv_state                         # (B, K-1, di+2n)
+        prev = conv_state                         # (B, K-1, di+2Gn)
         window = jnp.concatenate([prev, xBC], axis=1)          # (B, K, ·)
         xBC = jnp.einsum("bkc,kc->bc", window, p["conv_w"])[:, None]
         new_conv = window[:, 1:]
+    if "conv_b" in p:
+        xBC = xBC + p["conv_b"]
     xBC = jax.nn.silu(xBC.astype(jnp.float32)).astype(x.dtype)
-    xs, Bm, Cm = jnp.split(xBC, [di, di + n], axis=-1)
+    xs, Bm, Cm = split_xbc(xBC, cfg)
 
     dtp = jax.nn.softplus(dt.astype(jnp.float32) +
                           p["dt_bias"][None, None])             # (B,S,H)
     A = -jnp.exp(p["A_log"])                                    # (H,)
     xh = xs.reshape(*xs.shape[:2], h, pd)
 
-    if not decode:
-        y, final_state = ssd_chunked(xh, dtp, A, Bm, Cm, cfg.ssm_chunk,
+    with jax.named_scope("ssm_update"):
+        if not decode:
+            y, new_ssm = ssd_chunked(xh, dtp, A, Bm, Cm, cfg.ssm_chunk,
                                      compute_dtype=ssd_dtype)
-        new_ssm = final_state
-    else:
-        # single-step recurrence: state ← state*exp(dt·A) + dt·x ⊗ B
-        dA = jnp.exp(dtp[:, 0, :, None, None] * A[None, :, None, None])
-        xdt = (xh[:, 0].astype(jnp.float32) * dtp[:, 0, :, None])
-        upd = jnp.einsum("bhp,bn->bhpn", xdt, Bm[:, 0].astype(jnp.float32))
-        new_ssm = ssm_state * dA + upd
-        y = jnp.einsum("bhpn,bn->bhp", new_ssm,
-                       Cm[:, 0].astype(jnp.float32))[:, None]
-        final_state = new_ssm
+        else:
+            y, new_ssm = ssm_step(ssm_state, xh[:, 0], dtp[:, 0], A,
+                                  Bm[:, 0], Cm[:, 0])
+            y = y[:, None]
     y = y + p["D"][None, None, :, None] * xh.astype(jnp.float32)
     y = y.reshape(*xs.shape[:2], di).astype(x.dtype)
-    y = overlay_ops.ssm_gate(y, z)
-    y = L.rmsnorm(y, p["norm"], cfg.norm_eps)
-    out = y @ p["out_proj"]
+    out = gated_norm(y, z, p["norm"], cfg) @ p["out_proj"]
     if decode:
         return out, new_conv, new_ssm
     return out
@@ -213,6 +257,14 @@ class MambaLM:
                        "final_norm": sp(None)},
                 "layers": layer}
 
+    def _remat(self, fn):
+        if self.remat_policy == "full":
+            return jax.checkpoint(fn)
+        if self.remat_policy == "dots":
+            return jax.checkpoint(
+                fn, policy=jax.checkpoint_policies.checkpoint_dots)
+        return fn
+
     def _layer_train(self, x, lp):
         h = L.rmsnorm(x, lp["ln"], self.cfg.norm_eps)
         return x + mamba_block(lp["mamba"], h, self.cfg,
@@ -223,12 +275,7 @@ class MambaLM:
                       last_only: bool = False):
         cfg = self.cfg
         x = params["lm"]["embed"][tokens]
-        body = self._layer_train
-        if self.remat_policy == "full":
-            body = jax.checkpoint(body)
-        elif self.remat_policy == "dots":
-            body = jax.checkpoint(
-                body, policy=jax.checkpoint_policies.checkpoint_dots)
+        body = self._remat(self._layer_train)
 
         def step(x, lp):
             return body(x, lp), None

@@ -4,9 +4,9 @@ Where the pointwise math is overlay-expressible (DSP ops: ±, ×, min/max,
 fused mul-add), we JIT it through the full pipeline once at import of the
 using model and execute its DFG in "compiled mode" (a jnp expression
 generated from the routed graph — semantically the configured overlay, see
-DESIGN.md §4).  Transcendentals (exp in silu/softmax) are not DSP-block ops,
-so gated-silu splits: sigmoid stays jnp, the gating product and polynomial
-parts run on the overlay DFG.
+DESIGN.md §4).  Transcendentals (exp in silu/softmax, erf in gelu) are not
+DSP-block ops, so gated-silu and gated-gelu split: sigmoid and erf stay jnp,
+the gating product and polynomial parts run on the overlay DFG.
 
 The JIT'd kernels are cached process-wide; their CompiledKernel objects are
 inspectable (tests assert they really placed & routed).
@@ -57,6 +57,15 @@ def gated_silu(g, u):
     are the overlay datapath."""
     s = jax.nn.sigmoid(g.astype(jnp.float32)).astype(g.dtype)
     return _get("gate_mul2")(g, s, u)
+
+
+def gated_gelu(g, u):
+    """gelu(g) * u = g·Φ(g)·u, with Φ the normal CDF (exact, erf).  erf is
+    no DSP op (host jnp); the two products are the overlay datapath, the
+    one ``gated_silu`` uses."""
+    gf = g.astype(jnp.float32)
+    phi = (0.5 * (1.0 + jax.lax.erf(gf * 0.5 ** 0.5))).astype(g.dtype)
+    return _get("gate_mul2")(g, phi, u)
 
 
 def ssm_gate(y, z):
