@@ -102,10 +102,10 @@ def attention(p, x, cfg: ArchConfig, *, pos, kv: Optional[Tuple] = None,
     return _merge_heads(out) @ p["wo"]
 
 
-def attention_decode(p, x, cache_k, cache_v, cur_pos, cfg: ArchConfig,
-                     attn_impl: str = "ref"):
-    """One-token decode. x: (B, 1, d); cache: (B, Hkv, S, hd); cur_pos: ()
-    scalar — the index at which the new KV is written."""
+def decode_qkv(p, x, cur_pos, cfg: ArchConfig):
+    """The new position's query (B, Hq, 1, hd), key and value (B, Hkv, 1,
+    hd) for x: (B, 1, d), with qk-norm where ``cfg.qk_norm`` and the query
+    and key rotated to position ``cur_pos``."""
     hq, hkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.hd
     q = _split_heads(x @ p["wq"], hq, hd)                  # (B,Hq,1,hd)
     k_new = _split_heads(x @ p["wk"], hkv, hd)             # (B,Hkv,1,hd)
@@ -116,13 +116,30 @@ def attention_decode(p, x, cache_k, cache_v, cur_pos, cfg: ArchConfig,
     posv = jnp.full((1,), cur_pos, jnp.int32)
     q = apply_rope(q, posv, cfg.rope_theta)
     k_new = apply_rope(k_new, posv, cfg.rope_theta)
-    cache_k = lax.dynamic_update_slice(
-        cache_k, k_new.astype(cache_k.dtype), (0, 0, cur_pos, 0))
-    cache_v = lax.dynamic_update_slice(
-        cache_v, v_new.astype(cache_v.dtype), (0, 0, cur_pos, 0))
-    s = cache_k.shape[2]
-    # mask positions beyond cur_pos via logits masking: ref attention is
-    # causal w.r.t. aligned ends; for a mid-cache write we mask explicitly.
+    return q, k_new, v_new
+
+
+def decode_mask(s: int, cur_pos, cfg: ArchConfig, *, inclusive: bool):
+    """(S,) cache positions one query at ``cur_pos`` attends to: up to
+    ``cur_pos`` itself where ``inclusive``, else only those before it, and
+    none a window or more behind it where ``cfg.window``."""
+    kpos = jnp.arange(s)
+    mask = kpos <= cur_pos if inclusive else kpos < cur_pos
+    if cfg.window is not None:
+        mask &= kpos > cur_pos - cfg.window
+    return mask
+
+
+def attend_decode(p, q, cache_k, cache_v, mask, cfg: ArchConfig, out_dtype,
+                  row: Optional[Tuple] = None):
+    """One query position q: (B, Hq, 1, hd) over a slab of keys and values
+    (B, Hkv, S, hd) at the positions where ``mask`` (S,) holds, then the
+    output projection: (B, 1, d).  Logits and softmax are float32.
+
+    row: optionally the new position's own key and value (B, Hkv, 1, hd),
+    attended beside the slab as one more position, so that the slab can be
+    read as it was before the row is written into it."""
+    hq, hkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.hd
     kf = cache_k.astype(jnp.float32)
     vf = cache_v.astype(jnp.float32)
     scale = hd ** -0.5 if cfg.attn_scale is None else cfg.attn_scale
@@ -131,15 +148,38 @@ def attention_decode(p, x, cache_k, cache_v, cur_pos, cfg: ArchConfig,
     group = hq // hkv
     qg = qf.reshape(b, hkv, group, 1, hd)
     logits = jnp.einsum("bhgqd,bhkd->bhgqk", qg, kf)
-    kpos = jnp.arange(s)
-    mask = kpos <= cur_pos
-    if cfg.window is not None:
-        mask &= kpos > cur_pos - cfg.window
     logits = jnp.where(mask[None, None, None, None], logits, -1e30)
+    if row is not None:
+        k_row, v_row = (r.astype(jnp.float32) for r in row)
+        own = jnp.einsum("bhgqd,bhkd->bhgqk", qg, k_row)
+        logits = jnp.concatenate([logits, own], axis=-1)
     pr = jax.nn.softmax(logits, axis=-1)
-    out = jnp.einsum("bhgqk,bhkd->bhgqd", pr, vf).reshape(b, hq, 1, hd)
-    out = out.astype(x.dtype)
-    return _merge_heads(out) @ p["wo"], cache_k, cache_v
+    if row is None:
+        out = jnp.einsum("bhgqk,bhkd->bhgqd", pr, vf)
+    else:
+        s = cache_k.shape[2]
+        out = (jnp.einsum("bhgqk,bhkd->bhgqd", pr[..., :s], vf)
+               + jnp.einsum("bhgqk,bhkd->bhgqd", pr[..., s:], v_row))
+    out = out.reshape(b, hq, 1, hd).astype(out_dtype)
+    return _merge_heads(out) @ p["wo"]
+
+
+def attention_decode(p, x, cache_k, cache_v, cur_pos, cfg: ArchConfig,
+                     attn_impl: str = "ref"):
+    """One-token decode over one layer's own cache. x: (B, 1, d); cache:
+    (B, Hkv, S, hd); cur_pos: () scalar.  Writes the new key and value at
+    ``cur_pos`` first, then attends over the updated slab (positions up to
+    ``cur_pos``); returns (out (B, 1, d), cache_k, cache_v).  A layer
+    stack whose cache is one stacked array writes its row in place instead
+    (``DenseLM.forward_decode``)."""
+    q, k_new, v_new = decode_qkv(p, x, cur_pos, cfg)
+    cache_k = lax.dynamic_update_slice(
+        cache_k, k_new.astype(cache_k.dtype), (0, 0, cur_pos, 0))
+    cache_v = lax.dynamic_update_slice(
+        cache_v, v_new.astype(cache_v.dtype), (0, 0, cur_pos, 0))
+    mask = decode_mask(cache_k.shape[2], cur_pos, cfg, inclusive=True)
+    return (attend_decode(p, q, cache_k, cache_v, mask, cfg, x.dtype),
+            cache_k, cache_v)
 
 
 # -------------------------------------------------------------------- MLPs

@@ -166,21 +166,38 @@ class DenseLM:
 
     def forward_decode(self, params, cache, tokens, cur_pos):
         """tokens: (B, 1) int32; cur_pos: scalar int32 — write position.
-        Returns (logits (B, 1, V), new cache)."""
+        Returns (logits (B, 1, V), new cache).
+
+        The stacked cache is a carry of the layer scan.  Layer i reads its
+        slab ``cache[..][i]`` as it was before this step and attends over
+        the positions before ``cur_pos`` there, beside the new position's
+        own key and value; then it writes that one (B, Hkv, 1, hd) row of
+        keys and one of values at ``(i, 0, 0, cur_pos, 0)``.  Where the
+        caller donates the cache, the writes are in place: the step copies
+        neither a slab nor the whole cache."""
         cfg = self.cfg
         x = params["lm"]["embed"][tokens]                  # (B, 1, d)
+        mask = L.decode_mask(cache["k"].shape[3], cur_pos, cfg,
+                             inclusive=False)
 
-        def step(x, packed):
-            lp, ck, cv = packed
+        def step(carry, lp):
+            x, ck, cv, i = carry
             h = L.rmsnorm(x, lp["ln1"], cfg.norm_eps)
-            a, ck, cv = L.attention_decode(lp["attn"], h, ck, cv, cur_pos,
-                                           cfg, attn_impl=self.attn_impl)
+            q, k_new, v_new = L.decode_qkv(lp["attn"], h, cur_pos, cfg)
+            k_new, v_new = k_new.astype(ck.dtype), v_new.astype(cv.dtype)
+            a = L.attend_decode(lp["attn"], q,
+                                lax.dynamic_index_in_dim(ck, i, 0, False),
+                                lax.dynamic_index_in_dim(cv, i, 0, False),
+                                mask, cfg, x.dtype, row=(k_new, v_new))
+            at = (i, 0, 0, cur_pos, 0)
+            ck = lax.dynamic_update_slice(ck, k_new[None], at)
+            cv = lax.dynamic_update_slice(cv, v_new[None], at)
             x = x + a
             h = L.rmsnorm(x, lp["ln2"], cfg.norm_eps)
             x = x + L.mlp(lp["mlp"], h, cfg)
-            return x, (ck, cv)
+            return (x, ck, cv, i + 1), None
 
-        x, (new_k, new_v) = lax.scan(
-            step, x, (params["layers"], cache["k"], cache["v"]))
+        (x, new_k, new_v, _), _ = lax.scan(
+            step, (x, cache["k"], cache["v"], jnp.int32(0)), params["layers"])
         x = L.rmsnorm(x, params["lm"]["final_norm"], cfg.norm_eps)
         return x @ params["lm"]["unembed"], {"k": new_k, "v": new_v}

@@ -1,6 +1,8 @@
 """Per-architecture smoke tests (reduced configs): one forward/train step on
 CPU asserting shapes + finiteness, plus decode↔train consistency."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -110,6 +112,44 @@ def test_decode_matches_train_forward(arch):
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want, np.float32),
                                rtol=5e-2, atol=5e-2)
+
+
+@pytest.mark.parametrize("cur_pos", [0, 5, 11], ids=["first", "middle",
+                                                      "last"])
+@pytest.mark.parametrize("arch,window", [("yi-6b", None), ("yi-6b", 4),
+                                         ("qwen3-14b", None)],
+                         ids=["plain", "window", "qk_norm"])
+def test_dense_decode_matches_write_then_attend(arch, window, cur_pos):
+    """The dense decode reads each layer's slab before it writes the new
+    row, and attends to the row beside it: against the formulation that
+    writes first and attends over the updated slab, the cache it writes is
+    bitwise the same and the logits differ only by float32 rounding."""
+    import dataclasses
+    from reference_decode import forward_decode as write_then_attend
+    cfg = dataclasses.replace(reduced_config(ALL_ARCHS[arch]),
+                              dtype=jnp.float32, window=window)
+    assert cfg.qk_norm == (arch == "qwen3-14b")
+    model = build_model(cfg, remat_policy="none")
+    params = model.init(KEY)
+    b, s = 2, 12
+    kk, kv, kt = jax.random.split(jax.random.PRNGKey(4), 3)
+    shape = model.init_cache(b, s)["k"].shape
+    # every position holds a value, so a position wrongly attended to or
+    # wrongly left out moves the logits
+    cache = {"k": jax.random.normal(kk, shape).astype(jnp.bfloat16),
+             "v": jax.random.normal(kv, shape).astype(jnp.bfloat16)}
+    tok = jax.random.randint(kt, (b, 1), 0, cfg.vocab)
+    pos = jnp.int32(cur_pos)
+    got, got_cache = jax.jit(model.forward_decode)(params, cache, tok, pos)
+    want, want_cache = jax.jit(functools.partial(write_then_attend, model))(
+        params, cache, tok, pos)
+    for name in ("k", "v"):
+        assert got_cache[name].dtype == jnp.bfloat16
+        np.testing.assert_array_equal(
+            np.asarray(got_cache[name]).view(np.uint16),
+            np.asarray(want_cache[name]).view(np.uint16))
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-5, atol=1e-5)
 
 
 @pytest.mark.slow

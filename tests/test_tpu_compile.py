@@ -1,7 +1,10 @@
 """The main path's Pallas kernels, compiled at real sizes by the TPU
 compiler for one chip of a described v5e (nothing runs).  Each compile
 must contain the Mosaic kernel (``tpu_custom_call``): a kernel the chip's
-compiler refuses fails here, at no chip time.
+compiler refuses fails here, at no chip time.  The dense serve step is
+compiled too, at the decode cells' widths, for what only the TPU
+compiler's buffer assignment shows: whether the donated KV cache is
+updated in place.
 
 The topology is described inside a fixture, never at import: only one
 process may load the TPU library, and every test worker imports this file.
@@ -9,12 +12,18 @@ process may load the TPU library, and every test worker imports this file.
 
 import os
 
+import dataclasses
+import re
+
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
+from jax.sharding import PartitionSpec as P
 
 from repro.configs.paper_suite import BENCHMARKS
+from repro.configs.registry import ALL_ARCHS
 from repro.core.jit import jit_compile
 from repro.core.options import CompileOptions
 from repro.core.overlay import OverlaySpec
@@ -22,6 +31,8 @@ from repro.kernels.flash_attention.kernel import flash_attention
 from repro.kernels.overlay_exec import ops
 from repro.kernels.overlay_exec.kernel import overlay_execute
 from repro.kernels.rmsnorm.kernel import rmsnorm
+from repro.models.registry import build_model
+from repro.train.step import make_serve_step
 
 N_ITEMS = 1 << 24          # chip_smoke.py's work-items per overlay launch
 
@@ -98,3 +109,46 @@ def test_rmsnorm_d4096_bf16(one_chip, rows):
         jax.ShapeDtypeStruct((4096,), jnp.bfloat16, sharding=one_chip),
         interpret=False).compile()
     _assert_kernel(compiled)
+
+
+@pytest.mark.parametrize("arch,chips", [("yi-6b", 1), ("nemotron-4-15b", 4)])
+def test_dense_serve_step_updates_the_donated_cache_in_place(topo, arch,
+                                                             chips):
+    """The decode cells' serve step (batch 64, cache 512, the cache
+    donated; nemotron-4-15b on a (data=1, model=4) mesh) at the cells'
+    widths and 4 layers: the compiled step copies no whole stacked cache,
+    and its temporaries stay below one layer's key slab, so it holds no
+    second cache.  The CPU backend keeps whole-cache copies whether the
+    cache rows are written in place or not, so only a TPU compile can
+    check this."""
+    cfg = dataclasses.replace(ALL_ARCHS[arch], n_layers=4)
+    model = build_model(cfg)
+    mesh = Mesh(np.array(topo.devices[:chips]).reshape(1, chips),
+                ("data", "model"))
+
+    def abstract(shapes, specs):
+        return jax.tree.map(
+            lambda s, p: jax.ShapeDtypeStruct(
+                s.shape, s.dtype, sharding=NamedSharding(mesh, p)),
+            shapes, specs)
+
+    params = abstract(jax.eval_shape(model.init, jax.random.PRNGKey(0)),
+                      model.param_specs())
+    cache = abstract(jax.eval_shape(lambda: model.init_cache(64, 512)),
+                     model.cache_specs(model_axis=chips))
+    rep = NamedSharding(mesh, P())
+    compiled = jax.jit(
+        make_serve_step(model), donate_argnums=(1,),
+        out_shardings=(rep, jax.tree.map(lambda s: s.sharding, cache))).lower(
+        params, cache, jax.ShapeDtypeStruct((64, 1), jnp.int32, sharding=rep),
+        jax.ShapeDtypeStruct((), jnp.int32, sharding=rep)).compile()
+
+    k = cache["k"]
+    local = k.sharding.shard_shape(k.shape)      # (L, B, Hkv/chips, S, hd)
+    stacked = "bf16[" + ",".join(map(str, local)) + "]"
+    copies = [line for line in compiled.as_text().splitlines()
+              if re.search(r"= " + re.escape(stacked) + r"\{[^}]*\} copy\(",
+                           line)]
+    assert not copies, copies
+    slab = int(np.prod(local[1:])) * k.dtype.itemsize
+    assert compiled.memory_analysis().temp_size_in_bytes < slab

@@ -160,3 +160,28 @@ def test_serve_step_names_its_scopes():
         params, cache, toks[:, :1], jnp.int32(0)).compile().as_text()
     for name in ("mamba_layer", "ssm_update", "shared_block"):
         assert f"/{name}/" in text, name
+
+
+@pytest.mark.parametrize("cur_pos", [0, 7, T - 1])
+def test_site_attention_stays_write_then_attend(cur_pos):
+    """A site's keys and values are buffers of their own, outside the dense
+    layer scan: ``attention_decode`` still writes the new row first and
+    attends over the updated buffer, bitwise as the formulation the dense
+    decode left (``reference_decode``), at the cell's bfloat16."""
+    from reference_decode import attention_decode as write_then_attend
+    from repro.models import layers as L
+    c, model, params, toks, ref = _setup()
+    cfg = dataclasses.replace(model.cfg, dtype=jnp.bfloat16)
+    ka, kx, kk, kv = jax.random.split(jax.random.PRNGKey(9), 4)
+    p = L.init_attention(ka, cfg, d_in=2 * cfg.d_model)
+    x = jax.random.normal(kx, (2, 1, 2 * cfg.d_model)).astype(jnp.bfloat16)
+    shape = model.init_cache(2, T)["k"][0].shape
+    ck = jax.random.normal(kk, shape).astype(jnp.bfloat16)
+    cv = jax.random.normal(kv, shape).astype(jnp.bfloat16)
+    args = (p, x, ck, cv, jnp.int32(cur_pos))
+    got = jax.jit(L.attention_decode, static_argnums=5)(*args, cfg)
+    want = jax.jit(write_then_attend, static_argnums=5)(*args, cfg)
+    for g, w in zip(got, want):
+        assert g.dtype == w.dtype == jnp.bfloat16
+        np.testing.assert_array_equal(np.asarray(g).view(np.uint16),
+                                      np.asarray(w).view(np.uint16))
