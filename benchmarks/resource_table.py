@@ -9,6 +9,7 @@ from typing import Dict, List
 
 from repro.configs.paper_suite import BENCHMARKS
 from repro.core.jit import jit_compile
+from repro.core.options import CompileOptions
 from repro.core.overlay import OverlaySpec
 
 SPEC = OverlaySpec(width=8, height=8, dsp_per_fu=2)
@@ -27,7 +28,8 @@ PAPER_DIRECT = {
 def run() -> List[Dict]:
     rows = []
     for name, (src, paper_replicas, _) in sorted(BENCHMARKS.items()):
-        ck = jit_compile(src, SPEC, max_replicas=paper_replicas)
+        ck = jit_compile(src, SPEC,
+                         opts=CompileOptions(max_replicas=paper_replicas))
         res = ck.resources()
         direct = PAPER_DIRECT[name]
         rows.append({

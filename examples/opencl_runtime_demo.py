@@ -35,7 +35,9 @@ def main() -> None:
           f"load {prog.configure_overlay():.1f} us; "
           f"ledger: {dev.fu_used}/{dev.spec.n_fus} FUs in use")
     x = np.linspace(-2, 2, 1000).astype(np.float32)
-    queue = ctx.create_queue(use_overlay_executor=True)
+    # the backend decides the path: the Pallas executor on a TPU, the NumPy
+    # reference on the CPU (repro.kernels.interpret_mode)
+    queue = ctx.create_queue()
     ev = queue.enqueue_kernel(prog.create_kernel().set_args(Buffer(x)))
     (out,) = ev.wait()
     want = ((3 * x + 5) * x - 7) * x + 9

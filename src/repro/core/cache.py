@@ -20,8 +20,9 @@ content hash of everything that can change the produced configuration:
     a busy fleet's entries across every transient occupancy level and the
     cache almost never hit.  A build made when the overlay was empty is
     still never reused once the cap changes — the plan changes with it;
-  * the replication knobs (``max_replicas``, ``seed``, ``place_effort``)
-    and the P&R mode knobs (``pr_mode``, ``min_template_fill``).
+  * the build settings of :class:`~repro.core.options.CompileOptions` that
+    change the artifact (``CompileOptions.key_tail``: ``seed``,
+    ``place_effort``, ``pr_mode``, ``min_template_fill``).
 
 Eviction is LRU with a configurable capacity; hit/miss/eviction counters feed
 the serving dashboards (``benchmarks/jit_cache_perf.py``).
@@ -106,23 +107,14 @@ def make_cache_key(kernel: Union[str, Callable, DFG],
                    spec: OverlaySpec,
                    free_fus: int,
                    free_io: int,
-                   n_inputs: Optional[int] = None,
-                   name: Optional[str] = None,
-                   max_replicas: Optional[int] = None,
-                   seed: int = 0,
-                   place_effort: float = 1.0,
-                   pr_mode: str = "auto",
-                   min_template_fill: Optional[float] = None,
-                   fug=None,
-                   opts: Optional[CompileOptions] = None) -> CacheKey:
+                   opts: Optional[CompileOptions] = None,
+                   fug=None) -> CacheKey:
     """The full key: kernel content × overlay × *normalized* free-resource
-    snapshot × :class:`~repro.core.options.CompileOptions`.
+    snapshot × :class:`~repro.core.options.CompileOptions` (None means the
+    defaults).
 
-    The knob tail of the key IS ``opts.key_tail()`` — the frozen options
-    object replaced the ad-hoc tuple this function used to assemble, so a
-    knob added to CompileOptions is automatically part of the key.  The
-    loose keyword arguments survive as a shim: when ``opts`` is None they
-    are folded into one (legacy callers and tests keep working).
+    The knob tail of the key IS ``opts.key_tail()``, so a knob added to
+    CompileOptions is automatically part of the key.
 
     The snapshot is normalized to the replication plan it implies (the
     effective replica cap plus its limiting resource): ``jit_compile``
@@ -138,12 +130,7 @@ def make_cache_key(kernel: Union[str, Callable, DFG],
     """
     from repro.core.replicate import plan_replication
     if opts is None:
-        kw = {} if min_template_fill is None else \
-            dict(min_template_fill=min_template_fill)
-        opts = CompileOptions(n_inputs=n_inputs, name=name,
-                              max_replicas=max_replicas, seed=seed,
-                              place_effort=place_effort, pr_mode=pr_mode,
-                              **kw)
+        opts = CompileOptions()
     kf = kernel_fingerprint(kernel, n_inputs=opts.n_inputs, name=opts.name)
     if fug is None:
         from repro.core.fuse import to_fu_graph

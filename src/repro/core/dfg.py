@@ -7,7 +7,7 @@ edges carry 16/32-bit scalar values between them, inputs are ``invar`` nodes
 Two frontends build DFGs:
   * :mod:`repro.core.ir` — the OpenCL-C subset parser (paper's Clang/LLVM path),
   * :func:`trace` here — a Python operator-overloading tracer so JAX-side code
-    can declare pointwise kernels directly (``overlay_jit``).
+    can declare pointwise kernels directly (``jit_compile`` of a callable).
 """
 
 from __future__ import annotations

@@ -13,7 +13,8 @@ these) and three execution paths:
                          does NOT recompile XLA (the 42 µs-reconfig analogue).
   * ``run_reference``  — pure-numpy oracle.
 
-Two P&R strategies feed the place/route/latency stages (``pr_mode``):
+Two P&R strategies feed the place/route/latency stages
+(``CompileOptions.pr_mode``):
 
   * ``"template"`` — place & route ONE replica in a compact region, stamp
     R transformed copies on all four perimeter edges, and grow toward the
@@ -61,7 +62,7 @@ from repro.core.faults import InjectedFault, fault_point
 from repro.core.fuse import FUGraph, to_fu_graph
 from repro.core.ir import compile_opencl_to_dfg, _lower_consts
 from repro.core.latency import LatencyAssignment, balance
-from repro.core.options import CompileOptions, DEFAULT_MIN_TEMPLATE_FILL
+from repro.core.options import CompileOptions
 from repro.core.overlay import OverlaySpec
 from repro.core.place import Placement, place
 from repro.core.program import OverlayProgram, compile_program
@@ -70,8 +71,8 @@ from repro.core.replicate import ReplicationPlan, plan_replication, \
 from repro.core.route import RoutingResult, route
 from repro.obs import trace as obs_trace
 
-__all__ = ["CompiledKernel", "CompileOptions", "DEFAULT_MIN_TEMPLATE_FILL",
-           "jit_compile", "lower_cached", "lower_to_dfg", "overlay_jit"]
+__all__ = ["CompiledKernel", "CompileOptions", "jit_compile", "lower_cached",
+           "lower_to_dfg"]
 
 
 @dataclasses.dataclass
@@ -186,29 +187,18 @@ def lower_cached(kernel: Union[str, Callable, DFG],
 
 def jit_compile(kernel: Union[str, Callable, DFG],
                 spec: OverlaySpec,
-                n_inputs: Optional[int] = None,
-                name: Optional[str] = None,
-                max_replicas: Optional[int] = None,
+                opts: Optional[CompileOptions] = None,
+                *,
                 fu_headroom: int = 0,
                 io_headroom: int = 0,
-                seed: int = 0,
-                place_effort: float = 1.0,
-                cache: Optional["JITCache"] = None,
-                pr_mode: str = "auto",
-                min_template_fill: float = DEFAULT_MIN_TEMPLATE_FILL,
-                opts: Optional[CompileOptions] = None) -> CompiledKernel:
+                cache: Optional["JITCache"] = None) -> CompiledKernel:
     """Full JIT pipeline. Raises PlacementError/RoutingError/LatencyError on
     genuine mapping failures (kernel too big for the exposed overlay).
 
-    The canonical way to tune the build is one frozen
-    :class:`~repro.core.options.CompileOptions` value (``opts``) — the same
-    object the Session API and the cache key consume.  The loose keyword
-    knobs are the **deprecated** legacy shim: when ``opts`` is None they
-    are folded into one (and validated there) under a DeprecationWarning
-    if any build knob is actually set; when ``opts`` is given they are
-    ignored.  (``n_inputs``/``name`` alone stay silent — they describe the
-    kernel, not the build, and remain the convenient way to trace a python
-    callable.)
+    Every build setting travels in ``opts``, one frozen
+    :class:`~repro.core.options.CompileOptions` value (None means the
+    defaults) — the same object the Session API and the cache key consume.
+    ``opts.n_inputs``/``opts.name`` say how to trace a python callable.
 
     With ``cache``, the build is keyed on a content hash of (kernel, spec,
     effective replica cap implied by the free-resource snapshot,
@@ -220,21 +210,7 @@ def jit_compile(kernel: Union[str, Callable, DFG],
     the joint annealer.
     """
     if opts is None:
-        if (max_replicas is not None or seed != 0 or place_effort != 1.0
-                or pr_mode != "auto"
-                or min_template_fill != DEFAULT_MIN_TEMPLATE_FILL):
-            import warnings
-            warnings.warn(
-                "jit_compile with raw build knobs (max_replicas/seed/"
-                "place_effort/pr_mode/min_template_fill) is deprecated; "
-                "pass opts=CompileOptions(...) — see the ROADMAP "
-                "'Runtime v2' migration table",
-                DeprecationWarning, stacklevel=2)
-        # CompileOptions.__post_init__ validates pr_mode / fill range
-        opts = CompileOptions(n_inputs=n_inputs, name=name,
-                              max_replicas=max_replicas, seed=seed,
-                              place_effort=place_effort, pr_mode=pr_mode,
-                              min_template_fill=min_template_fill)
+        opts = CompileOptions()
     n_inputs, name = opts.n_inputs, opts.name
     times: Dict[str, float] = {}
 
@@ -476,13 +452,3 @@ def _template_par(fug: FUGraph, g: DFG, spec: OverlaySpec,
         plan = plan.with_replicas(fug, replicas, "stamp")
     return placement, routing, lat, plan
 
-
-def overlay_jit(fn: Callable, n_inputs: int, spec: Optional[OverlaySpec] = None,
-                **kw) -> CompiledKernel:
-    """Decorator-style helper for JAX model code: declare a pointwise
-    datapath as an overlay kernel.
-
-    >>> swish_poly = overlay_jit(lambda x: x * (x * (x * 0.044715 + 1.0)), 1)
-    """
-    spec = spec or OverlaySpec()
-    return jit_compile(fn, spec, n_inputs=n_inputs, **kw)

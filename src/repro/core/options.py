@@ -1,10 +1,6 @@
-"""Compile options — ONE frozen, hashable object instead of a knob soup.
-
-Every tunable the JIT pipeline accepts used to travel as loose keyword
-arguments (``jit_compile(source, spec, max_replicas=..., seed=...,
-place_effort=..., pr_mode=..., min_template_fill=..., ...)``) and was
-re-assembled into an ad-hoc tuple inside ``make_cache_key``.  The Session
-API collapses them into :class:`CompileOptions`:
+"""Compile options — the one frozen, hashable value that carries every
+build setting into ``jit_compile``, ``Context.build_program``,
+``Scheduler.build``, ``Session.compile`` and ``make_cache_key``:
 
   * it is **frozen** (hashable, comparable) — a CompileOptions value can key
     a dict, deduplicate in-flight builds (the Session's single-flight map),
@@ -39,11 +35,7 @@ _VERIFY_LEVELS = ("off", "fused", "full")
 
 @dataclasses.dataclass(frozen=True)
 class CompileOptions:
-    """Everything a caller can turn on the JIT pipeline, in one value.
-
-    Fields mirror the historical ``jit_compile`` keywords exactly, so the
-    migration is mechanical (see ROADMAP "Runtime v2" migration table).
-    """
+    """Everything a caller can turn on the JIT pipeline, in one value."""
     n_inputs: Optional[int] = None       # arity when tracing a python callable
     name: Optional[str] = None           # kernel display name (never keyed)
     max_replicas: Optional[int] = None   # cap on resource-aware replication
@@ -114,8 +106,8 @@ class CompileOptions:
         ``deadline_ms`` are absent for the same reason: they steer *when a
         build gives up*, never what it produces, and a kernel that needed a
         retry must still warm the cache for callers with no retry budget.
-        The format matches the pre-Session ad-hoc tuple byte for byte, so
-        existing disk-cache tiers stay warm across the API migration."""
+        The byte format is part of every persisted cache key: changing it
+        turns every disk-cache entry cold."""
         return (f"{self.seed}:{self.place_effort:g}:{self.pr_mode}:"
                 f"{self.min_template_fill:g}")
 

@@ -90,12 +90,10 @@ class CommandQueue:
     """
 
     def __init__(self, context: "Context", in_order: bool = True,
-                 use_overlay_executor: Optional[bool] = None,
                  tenant: Optional[str] = None):
         self.ctx = context
         self.device = context.device
         self.in_order = in_order
-        self.use_overlay_executor = use_overlay_executor
         # which tenant's submission stream this is (the Session keeps one
         # queue per (tenant, device)); purely a label for profiles/dashboards
         self.tenant = tenant
@@ -188,8 +186,7 @@ class CommandQueue:
         # timeline: a failed enqueue must not leave a phantom busy interval
         # or config switch behind
         fault_point("device_exec", ck.name)
-        outputs = kernel.enqueue(
-            use_overlay_executor=self.use_overlay_executor)
+        outputs = kernel.enqueue()
 
         t_queued = 0.0
         ready = max([d.t_end_us for d in deps], default=0.0)

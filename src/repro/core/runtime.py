@@ -41,7 +41,6 @@ from __future__ import annotations
 import dataclasses
 import threading
 import time
-import warnings
 from typing import Callable, Dict, List, Optional, Sequence, Tuple, Union
 
 import numpy as np
@@ -215,33 +214,19 @@ class Context:
 
     # ----------------------------------------------------------- programs
     def build_program(self, source: Union[str, Callable],
-                      n_inputs: Optional[int] = None,
-                      max_replicas: Optional[int] = None,
-                      name: Optional[str] = None,
                       opts: Optional[CompileOptions] = None,
                       tenant: Optional[str] = None) -> "Program":
         """clBuildProgram: JIT-compile against the *currently free* overlay
         resources exposed by the device, then debit the ledger with the
         plan's FU/IO usage (credited back by :meth:`Program.release`).
 
-        ``opts`` is the canonical way to tune the build; the loose keywords
-        are a **deprecated** legacy shim folded into a CompileOptions when
-        it is absent (the Session core always passes ``opts``).
+        Build settings travel in ``opts`` (None means the defaults).
         Compile + debit happen under the context lock, so the headroom a
         build plans against cannot be invalidated mid-pipeline by a
         concurrent build or release on the same device."""
         if self.device.failed:
             raise DeviceLostError(
                 f"device {self.device.name} is failed; cannot build")
-        if opts is None:
-            warnings.warn(
-                "Context.build_program(source, n_inputs=..., ...) with "
-                "loose keywords is deprecated; use Session.build(source, "
-                "CompileOptions(n_inputs=...), tenant=...) — see the "
-                "ROADMAP 'Runtime v2' migration table",
-                DeprecationWarning, stacklevel=2)
-            opts = CompileOptions(n_inputs=n_inputs, name=name,
-                                  max_replicas=max_replicas)
         with self.lock:
             t0 = time.perf_counter()
             ck = jit_compile(source, self.device.spec, opts=opts,
@@ -279,12 +264,9 @@ class Context:
 
     # -------------------------------------------------------------- queues
     def create_queue(self, in_order: bool = True,
-                     use_overlay_executor: Optional[bool] = None,
                      tenant: Optional[str] = None):
         from repro.core.queue import CommandQueue
-        return CommandQueue(self, in_order=in_order,
-                            use_overlay_executor=use_overlay_executor,
-                            tenant=tenant)
+        return CommandQueue(self, in_order=in_order, tenant=tenant)
 
     def ledger_consistent(self) -> bool:
         """Invariant: device usage == reservations + resident programs."""
@@ -391,12 +373,12 @@ class Kernel:
     def work_items(self) -> int:
         return int(self.args[0].data.size) if self.args else 1
 
-    def enqueue(self, use_overlay_executor: Optional[bool] = None):
+    def enqueue(self):
         """clEnqueueNDRangeKernel: run over all work-items of the buffers.
 
-        ``use_overlay_executor=None`` lets the backend decide
-        (:func:`repro.kernels.interpret_mode`): the compiled Pallas executor
-        on an accelerator, the NumPy reference on the CPU backend."""
+        The backend decides the path (:func:`repro.kernels.interpret_mode`):
+        the compiled Pallas executor on an accelerator, the NumPy reference
+        on the CPU backend."""
         if self.program.released:
             raise RuntimeError_(
                 "kernel's program was released; its fabric may already be "
@@ -406,9 +388,7 @@ class Kernel:
         if len(ins) != len(ck.dfg.inputs):
             raise RuntimeError_(
                 f"kernel expects {len(ck.dfg.inputs)} buffers, got {len(ins)}")
-        if use_overlay_executor is None:
-            use_overlay_executor = not interpret_mode()
-        if use_overlay_executor:
+        if not interpret_mode():
             sig = self.program.executor_signature()
             outs = ck.run_overlay(*ins, pad_to=sig[0], pad_regs=sig[1])
             self.program.exec_signature = sig
@@ -576,7 +556,7 @@ class Scheduler:
         Returns a token for :meth:`release_inflight`.
 
         The ranking read here is advisory (a placement *hint*, re-ranked
-        for real inside :meth:`build_opts`), so it deliberately skips the
+        for real inside :meth:`build`), so it deliberately skips the
         fleet lock — booking must not block behind a build that is holding
         it for a full pipeline run."""
         est = self.estimate_build_us(fingerprint)
@@ -600,36 +580,15 @@ class Scheduler:
 
     # ------------------------------------------------------------ placement
     def build(self, source: Union[str, Callable],
-              n_inputs: Optional[int] = None,
-              name: Optional[str] = None,
-              max_replicas: Optional[int] = None,
-              max_shed_rounds: int = 8) -> Program:
-        """**Deprecated** legacy entry point — a thin shim folding the loose
-        knobs into a :class:`CompileOptions` and delegating to
-        :meth:`build_opts` (the Session core), so both paths exercise one
-        implementation.  New code wants
-        ``Session.compile(source, CompileOptions(...)).result()``."""
-        warnings.warn(
-            "Scheduler.build(source, max_replicas=...) is deprecated; use "
-            "Session.compile(source, CompileOptions(max_replicas=...))"
-            ".result() or Scheduler.build_opts — see the ROADMAP "
-            "'Runtime v2' migration table",
-            DeprecationWarning, stacklevel=2)
-        return self.build_opts(
-            source, CompileOptions(n_inputs=n_inputs, name=name,
-                                   max_replicas=max_replicas),
-            max_shed_rounds=max_shed_rounds)
-
-    def build_opts(self, source: Union[str, Callable],
-                   opts: Optional[CompileOptions] = None,
-                   tenant: Optional[str] = None,
-                   max_shed_rounds: int = 8,
-                   inflight: Optional[Tuple[Context, float]] = None,
-                   fingerprint: Optional[str] = None) -> Program:
+              opts: Optional[CompileOptions] = None,
+              tenant: Optional[str] = None,
+              max_shed_rounds: int = 8,
+              inflight: Optional[Tuple[Context, float]] = None,
+              fingerprint: Optional[str] = None) -> Program:
         """Place + JIT-build ``source`` on the best device per the placement
         policy; returns the resident Program (release() it to free fabric).
-        This is the core every entry point funnels into — ``Session.compile``
-        submits it to the worker pool, :meth:`build` calls it inline.
+        Build settings travel in ``opts`` (None means the defaults).
+        ``Session.compile`` submits this to its worker pool.
 
         ``inflight`` is the booking token the Session posted at submit time
         (see :meth:`book_inflight`); it is excluded from this build's own

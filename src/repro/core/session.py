@@ -241,7 +241,6 @@ class Session:
                  persist_dir: Optional[str] = None,
                  max_workers: int = 4,
                  policy: str = "makespan",
-                 use_overlay_executor: Optional[bool] = None,
                  faults: Optional[FaultPlan] = None,
                  retry: Optional[RetryPolicy] = None,
                  remote=None,
@@ -258,10 +257,6 @@ class Session:
             # lookup to the local tiers, never fails a build
             self.scheduler.cache.remote = remote
         self.platform = Platform(list(self.scheduler.devices))
-        # None: the backend decides per enqueue (repro.kernels.interpret_mode
-        # — compiled Pallas executor on an accelerator, NumPy reference on
-        # the CPU); True/False force one path
-        self.use_overlay_executor = use_overlay_executor
         # chaos + self-healing plane: the fault plan (if any) is activated
         # thread-locally around every worker-pool build and every enqueue;
         # the retry policy parameterizes backoff/hedging/breakers and the
@@ -420,7 +415,7 @@ class Session:
                         if opts.deadline_ms is not None:
                             return self._build_hedged(source, opts, tenant,
                                                       booking, fp)
-                        return self.scheduler.build_opts(
+                        return self.scheduler.build(
                             source, opts, tenant=tenant, inflight=booking,
                             fingerprint=fp)
                     except Exception as e:
@@ -458,7 +453,7 @@ class Session:
                     obs_trace.span(f"jit:racer:{tag}", "compile",
                                    kernel=o.name or fp[:12]):
                 try:
-                    resq.put((tag, self.scheduler.build_opts(
+                    resq.put((tag, self.scheduler.build(
                         source, o, tenant=tenant, inflight=booking,
                         fingerprint=fp), None))
                 except BaseException as e:
@@ -531,9 +526,7 @@ class Session:
             q = self._queues.get(key)
             if q is None:
                 q = self.scheduler.contexts[device_name].create_queue(
-                    in_order=bool(in_order),
-                    use_overlay_executor=self.use_overlay_executor,
-                    tenant=key[0])
+                    in_order=bool(in_order), tenant=key[0])
                 self._queues[key] = q
             elif in_order is not None and q.in_order != in_order:
                 raise SessionError(

@@ -56,11 +56,16 @@ def test_forced_compile_raises_on_cpu(call):
         call(False)
 
 
-@pytest.mark.parametrize("executor", [None, True], ids=["default", "forced"])
+@pytest.mark.parametrize("forced", [False, True], ids=["default", "forced"])
 @pytest.mark.parametrize("name", sorted(BENCHMARKS))
-def test_session_matches_paper_suite_oracles(name, executor, monkeypatch):
+def test_session_matches_paper_suite_oracles(name, forced, monkeypatch):
     """Default on the CPU: the NumPy reference runs, never the executor.
-    Forced: the executor runs (interpreted) and agrees as well."""
+    Forced (the runtime told it is on an accelerator): the executor runs,
+    still interpreted, and agrees as well."""
+    if forced:
+        import repro.core.runtime
+        monkeypatch.setattr(repro.core.runtime, "interpret_mode",
+                            lambda interpret=None: False)
     calls = []
     run_overlay = CompiledKernel.run_overlay
     monkeypatch.setattr(CompiledKernel, "run_overlay",
@@ -68,11 +73,10 @@ def test_session_matches_paper_suite_oracles(name, executor, monkeypatch):
                         run_overlay(self, *a, **kw))
     src, _, oracle = BENCHMARKS[name]
     rng = np.random.default_rng(0)
-    with Session([Device("ovl0", SPEC), Device("ovl1", SPEC)],
-                 use_overlay_executor=executor) as sess:
+    with Session([Device("ovl0", SPEC), Device("ovl1", SPEC)]) as sess:
         fut = sess.compile(src, CompileOptions(max_replicas=2))
         xs = [rng.uniform(-1, 1, 2048).astype(np.float32)
               for _ in fut.result().compiled.dfg.inputs]
         got = sess.enqueue(fut, *xs).wait()[0].read()
     np.testing.assert_allclose(got, oracle(*xs), rtol=1e-4, atol=1e-4)
-    assert len(calls) == (1 if executor else 0)
+    assert len(calls) == (1 if forced else 0)

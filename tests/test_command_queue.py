@@ -7,6 +7,7 @@ import pytest
 
 from repro.configs.paper_suite import BENCHMARKS
 from repro.core.cache import JITCache
+from repro.core.options import CompileOptions
 from repro.core.overlay import OverlaySpec
 from repro.core.queue import user_event
 from repro.core.runtime import (Buffer, Context, Device, Scheduler,
@@ -107,13 +108,15 @@ def test_barrier_blocks_backfill_on_out_of_order_queue():
 
 def test_reconfiguration_charged_once_per_program():
     ctx = _ctx()
-    p1 = ctx.build_program(BENCHMARKS["poly1"][0], max_replicas=4)
+    p1 = ctx.build_program(BENCHMARKS["poly1"][0],
+                           opts=CompileOptions(max_replicas=4))
     q = ctx.create_queue()
     e1 = q.enqueue_kernel(p1.create_kernel().set_args(Buffer(X)))
     e2 = q.enqueue_kernel(p1.create_kernel().set_args(Buffer(X)))
     assert e1.config_us > 0.0                 # first load pays the config
     assert e2.config_us == 0.0                # overlay already configured
-    p2 = ctx.build_program(BENCHMARKS["chebyshev"][0], max_replicas=4)
+    p2 = ctx.build_program(BENCHMARKS["chebyshev"][0],
+                           opts=CompileOptions(max_replicas=4))
     e3 = q.enqueue_kernel(p2.create_kernel().set_args(Buffer(X)))
     e4 = q.enqueue_kernel(p1.create_kernel().set_args(Buffer(X)))
     assert e3.config_us > 0.0                 # kernel swap reconfigures
@@ -238,7 +241,7 @@ def test_queue_rejects_program_from_other_device():
     queue — timing and config history would silently be wrong."""
     sched = Scheduler([Device("a", SPEC), Device("b", SPEC)])
     pa = sched.contexts["a"].build_program(BENCHMARKS["poly1"][0],
-                                           max_replicas=2)
+                                           opts=CompileOptions(max_replicas=2))
     qb = sched.contexts["b"].create_queue()
     with pytest.raises(RuntimeError):
         qb.enqueue_kernel(pa.create_kernel().set_args(Buffer(X)))

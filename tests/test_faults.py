@@ -380,15 +380,15 @@ def test_deadline_miss_spawns_hedge_and_faster_build_wins(monkeypatch):
     in-pipeline slow on a one-device fleet serializes the racers on
     ctx.lock instead, covered by the chaos-plan test below.)"""
     with Session([Device("a", SPEC)], retry=FAST) as sess:
-        real = sess.scheduler.build_opts
+        real = sess.scheduler.build
         stalled = threading.Event()
 
-        def build_opts(source, opts, **kw):
+        def build(source, opts, **kw):
             if opts.place_effort >= 0.5:          # the primary, full effort
                 stalled.wait(10)                  # stall until hedge landed
             return real(source, opts, **kw)
 
-        monkeypatch.setattr(sess.scheduler, "build_opts", build_opts)
+        monkeypatch.setattr(sess.scheduler, "build", build)
         fut = sess.compile(POLY1, CompileOptions(max_replicas=4,
                                                  deadline_ms=80.0))
         prog = fut.result(60)

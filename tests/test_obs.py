@@ -219,11 +219,14 @@ def test_execute_books_launch_spans_in_order():
     assert pad.name == "launch:pad" and pad.parent == spans[0].sid
 
 
-def test_session_enqueue_span_wraps_the_launch():
+def test_session_enqueue_span_wraps_the_launch(monkeypatch):
+    import repro.core.runtime
+    # the executor path, which still interprets on the CPU
+    monkeypatch.setattr(repro.core.runtime, "interpret_mode",
+                        lambda interpret=None: False)
     tr = Tracer()
     x = np.linspace(-1, 1, 300).astype(np.float32)
-    with Session([Device("a", SPEC)], use_overlay_executor=True,
-                 tracer=tr) as sess:
+    with Session([Device("a", SPEC)], tracer=tr) as sess:
         fut = sess.compile(POLY1, OPTS, tenant="t")
         ev = sess.enqueue(fut, x)
         with activate(tr):

@@ -214,10 +214,12 @@ def test_shared_signature(resident, shared):
     assert ops.shared_signature(prog, resident) == (shared or own)
 
 
-def test_session_swap_shares_executable():
+def test_session_swap_shares_executable(monkeypatch):
     """Through the Session, poly1 runs at the signature of the resident
     chebyshev and so compiles no executor; poly2 fits none and runs at its
-    own."""
+    own.  The runtime is told it is on an accelerator, so it takes the
+    executor, which still interprets on the CPU."""
+    import repro.core.runtime
     from repro.configs.paper_suite import BENCHMARKS
     from repro.core.options import CompileOptions
     from repro.core.overlay import OverlaySpec
@@ -228,8 +230,9 @@ def test_session_swap_shares_executable():
     spec = OverlaySpec(width=8, height=8, dsp_per_fu=2)
     x = np.linspace(-1, 1, 1024).astype(np.float32)
     progs, compiled = {}, {}
-    with Session([Device("ovl0", spec), Device("ovl1", spec)],
-                 use_overlay_executor=True) as sess:
+    monkeypatch.setattr(repro.core.runtime, "interpret_mode",
+                        lambda interpret=None: False)
+    with Session([Device("ovl0", spec), Device("ovl1", spec)]) as sess:
         for name in ("chebyshev", "poly1", "poly2"):
             fut = sess.compile(BENCHMARKS[name][0],
                                CompileOptions(max_replicas=2))

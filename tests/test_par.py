@@ -4,6 +4,7 @@ import pytest
 
 from repro.core.bitstream import parse_header
 from repro.core.jit import jit_compile
+from repro.core.options import CompileOptions
 from repro.core.overlay import OverlaySpec, RoutingGraph
 from repro.core.place import PlacementError
 from repro.configs.paper_suite import BENCHMARKS
@@ -95,11 +96,11 @@ def test_kernel_too_big_raises():
     tiny = OverlaySpec(width=2, height=2, dsp_per_fu=1)
     big_src = BENCHMARKS["sgfilter"][0]
     with pytest.raises(PlacementError):
-        jit_compile(big_src, tiny, max_replicas=None)
+        jit_compile(big_src, tiny)
 
 
 def test_deterministic_given_seed():
-    a = jit_compile(BENCHMARKS["poly1"][0], SPEC, seed=7)
-    b = jit_compile(BENCHMARKS["poly1"][0], SPEC, seed=7)
+    a = jit_compile(BENCHMARKS["poly1"][0], SPEC, opts=CompileOptions(seed=7))
+    b = jit_compile(BENCHMARKS["poly1"][0], SPEC, opts=CompileOptions(seed=7))
     assert a.bitstream.data == b.bitstream.data
     assert a.placement.fu_pos == b.placement.fu_pos

@@ -13,6 +13,7 @@ from repro.core.dfg import optimize, trace
 from repro.core.fuse import fuse_muladd
 from repro.core.ir import _lower_consts
 from repro.core.jit import jit_compile
+from repro.core.options import CompileOptions
 from repro.core.overlay import OverlaySpec
 from repro.core.program import compile_program
 from repro.core.replicate import plan_replication
@@ -171,8 +172,9 @@ def test_full_jit_pipeline_random_kernels(seed):
     def kern(x):
         return ((coeffs[0] * x + coeffs[1]) * x + coeffs[2]) * x + coeffs[3]
 
-    ck = jit_compile(kern, OverlaySpec(), n_inputs=1, name=f"rand{seed}",
-                     place_effort=0.2)
+    ck = jit_compile(kern, OverlaySpec(),
+                     opts=CompileOptions(n_inputs=1, name=f"rand{seed}",
+                                         place_effort=0.2))
     x = np.linspace(-1, 1, 64).astype(np.float32)
     want = ((coeffs[0] * x + coeffs[1]) * x + coeffs[2]) * x + coeffs[3]
     np.testing.assert_allclose(ck.run_reference(x), want, rtol=1e-4,

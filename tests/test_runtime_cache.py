@@ -9,6 +9,7 @@ from repro.core.cache import (JITCache, dfg_fingerprint, kernel_fingerprint,
                               make_cache_key)
 from repro.core.dfg import trace
 from repro.core.jit import jit_compile
+from repro.core.options import CompileOptions
 from repro.core.overlay import OverlaySpec
 from repro.core.runtime import Buffer, Context, Device
 
@@ -57,7 +58,7 @@ def test_key_normalizes_snapshot_to_replica_cap():
     assert k2 != k0
     # pr_mode / fill knobs are part of the key
     assert make_cache_key(CHEB, SPEC, free_fus=64, free_io=64,
-                          pr_mode="joint") != k0
+                          opts=CompileOptions(pr_mode="joint")) != k0
 
 
 def test_busy_fleet_occupancy_jitter_still_hits():
@@ -67,11 +68,11 @@ def test_busy_fleet_occupancy_jitter_still_hits():
     cache = JITCache()
     ctx = Context(Device("d", SPEC), cache=cache)
     ctx.reserve(fus=1)                      # sub-replica occupancy jitter
-    p1 = ctx.build_program(CHEB, max_replicas=4)
+    p1 = ctx.build_program(CHEB, opts=CompileOptions(max_replicas=4))
     p1.release()
     ctx.release(fus=1)
     ctx.reserve(fus=2)                      # different snapshot, same cap
-    p2 = ctx.build_program(CHEB, max_replicas=4)
+    p2 = ctx.build_program(CHEB, opts=CompileOptions(max_replicas=4))
     assert p2.compiled is p1.compiled
     assert cache.stats.hits == 1 and cache.stats.misses == 1
     assert cache.stats.hit_rate == 0.5
@@ -136,7 +137,7 @@ def test_build_debits_ledger_and_release_credits():
     resources — two builds can no longer each claim the full overlay."""
     ctx = Context(Device("d", SPEC))
     free0 = ctx.device.fu_free
-    p1 = ctx.build_program(CHEB, max_replicas=8)
+    p1 = ctx.build_program(CHEB, opts=CompileOptions(max_replicas=8))
     assert ctx.device.fu_free == free0 - p1.compiled.plan.fus_used
     assert ctx.device.io_free == SPEC.n_io - p1.compiled.plan.io_used
     p2 = ctx.build_program(CHEB)           # compiled against the remainder
@@ -157,7 +158,7 @@ def test_over_release_of_reservation_rejected():
     """Crediting more than the outstanding reservation would un-book fabric
     owned by resident programs."""
     ctx = Context(Device("d", SPEC))
-    prog = ctx.build_program(POLY1, max_replicas=4)
+    prog = ctx.build_program(POLY1, opts=CompileOptions(max_replicas=4))
     ctx.reserve(fus=4)
     with pytest.raises(RuntimeError):
         ctx.release(fus=10)            # > outstanding reservation

@@ -41,29 +41,47 @@ def test_compile_options_frozen_hashable_validated():
 
 
 def test_compile_options_is_the_cache_key_tail():
-    """The opts object and the legacy loose kwargs must produce the SAME
-    key — the options object replaced the ad-hoc tuple, not the format."""
-    legacy = make_cache_key(CHEB, SPEC, free_fus=64, free_io=64,
-                            max_replicas=4, seed=2, place_effort=0.5,
-                            pr_mode="template")
+    """``key_tail`` is hashed into every persisted cache key: its bytes are
+    pinned, or every disk-cache entry would go cold.  The seed, which it
+    carries, changes the key."""
+    opts = CompileOptions(max_replicas=4, seed=2, place_effort=0.5,
+                          pr_mode="template")
+    assert opts.key_tail() == "2:0.5:template:0.95"
     via_opts = make_cache_key(CHEB, SPEC, free_fus=64, free_io=64,
-                              opts=CompileOptions(max_replicas=4, seed=2,
-                                                  place_effort=0.5,
-                                                  pr_mode="template"))
-    assert legacy == via_opts
+                              opts=opts)
     assert via_opts != make_cache_key(
         CHEB, SPEC, free_fus=64, free_io=64,
         opts=CompileOptions(max_replicas=4, seed=3, place_effort=0.5,
                             pr_mode="template"))
 
 
-def test_jit_compile_opts_and_kwargs_share_one_entry():
-    cache = JITCache()
-    a = jit_compile(POLY1, SPEC, max_replicas=4, seed=1, cache=cache)
-    b = jit_compile(POLY1, SPEC, cache=cache,
-                    opts=CompileOptions(max_replicas=4, seed=1))
-    assert b is a
-    assert cache.stats.hits == 1 and cache.stats.misses == 1
+def _jit(**kw):
+    return jit_compile(POLY1, SPEC, **kw)
+
+
+def _build_program(**kw):
+    from repro.core.runtime import Context
+    return Context(Device("a", SPEC)).build_program(POLY1, **kw)
+
+
+def _scheduler_build(**kw):
+    return Scheduler([Device("a", SPEC)]).build(POLY1, **kw)
+
+
+def _cache_key(**kw):
+    return make_cache_key(POLY1, SPEC, free_fus=64, free_io=64, **kw)
+
+
+@pytest.mark.parametrize("entry", [_jit, _build_program, _scheduler_build,
+                                   _cache_key],
+                         ids=["jit_compile", "build_program",
+                              "scheduler_build", "make_cache_key"])
+def test_build_settings_travel_only_in_opts(entry):
+    """Each entry point takes its build settings in ``opts`` alone: a loose
+    build keyword is refused, and ``opts`` is accepted."""
+    with pytest.raises(TypeError, match="max_replicas"):
+        entry(max_replicas=4)
+    assert entry(opts=CompileOptions(max_replicas=4)) is not None
 
 
 # ------------------------------------------------------------- async compile
@@ -211,7 +229,7 @@ def test_build_estimates_converge_to_observed_times():
     sched = Scheduler([Device("a", SPEC)])
     fp = kernel_fingerprint(POLY1)
     assert sched.estimate_build_us(fp) == DEFAULT_BUILD_EST_US
-    prog = sched.build_opts(POLY1, CompileOptions(max_replicas=2))
+    prog = sched.build(POLY1, CompileOptions(max_replicas=2))
     est = sched.estimate_build_us(fp)
     assert est == pytest.approx(prog.build_ms * 1e3)
     # ...and the Session's submit-time booking reads the refined estimate
@@ -227,65 +245,34 @@ def test_low_priority_tenant_is_shed_first():
     spec = OverlaySpec(width=4, height=4, dsp_per_fu=2)
     sched = Scheduler([Device("a", spec)])
     sched.set_priority("gold", 10)
-    gold = sched.build_opts(POLY1, CompileOptions(max_replicas=3),
-                            tenant="gold")
-    bronze = sched.build_opts(CHEB, CompileOptions(max_replicas=2),
-                              tenant="bronze")
+    gold = sched.build(POLY1, CompileOptions(max_replicas=3),
+                       tenant="gold")
+    bronze = sched.build(CHEB, CompileOptions(max_replicas=2),
+                         tenant="bronze")
     assert (gold.compiled.plan.replicas, bronze.compiled.plan.replicas) \
         == (3, 2)
     # sgfilter needs 7 FUs/replica; only 4 free -> forces one shed round
-    third = sched.build_opts(BENCHMARKS["sgfilter"][0],
-                             CompileOptions(max_replicas=1), tenant="new")
+    third = sched.build(BENCHMARKS["sgfilter"][0],
+                        CompileOptions(max_replicas=1), tenant="new")
     assert third.compiled.plan.replicas == 1
     assert gold.compiled.plan.replicas == 3          # priority kept intact
     assert bronze.compiled.plan.replicas == 1        # bronze paid the bill
     assert sched.ledger_consistent()
 
 
-# ------------------------------------------------------------- legacy shims
+# ------------------------------------------------- one core under the Session
 
 def test_legacy_entry_points_share_the_session_core():
-    """Scheduler.build and Context.build_program are shims over the opts
-    path: same knobs -> same cache entry as build_opts/Session."""
-    sched = Scheduler([Device("a", SPEC), Device("b", SPEC)])
-    with pytest.warns(DeprecationWarning):
-        p0 = sched.build(POLY1, max_replicas=4)              # legacy shim
-    p1 = sched.build_opts(POLY1, CompileOptions(max_replicas=4))
-    assert p1.compiled is p0.compiled                        # cache hit
-    assert p0.opts == CompileOptions(max_replicas=4)
-    ctx = sched.contexts[p0.ctx.device.name]
-    assert ctx.ledger_consistent()
-
-
-def test_legacy_shims_warn_deprecation_with_unchanged_behavior():
-    """ISSUE 5 satellite: every legacy entry point warns ONCE toward its
-    Session/CompileOptions replacement (ROADMAP migration table) while
-    producing the same artifact as the opts-first path."""
-    from repro.core.runtime import Context
+    """``Scheduler.build`` and ``Session.compile`` with equal opts share one
+    cache entry: the Session runs the scheduler's one build method."""
     cache = JITCache()
-    new = jit_compile(POLY1, SPEC, cache=cache,
-                      opts=CompileOptions(max_replicas=4, seed=1))
-    with pytest.warns(DeprecationWarning, match="CompileOptions"):
-        old = jit_compile(POLY1, SPEC, max_replicas=4, seed=1, cache=cache)
-    assert old is new                              # same cache entry
-
-    ctx = Context(Device("a", SPEC), cache=cache)
-    with pytest.warns(DeprecationWarning, match="Session.build"):
-        p_old = ctx.build_program(POLY1, max_replicas=4)
-    p_old.release()
-    p_new = ctx.build_program(POLY1, opts=CompileOptions(max_replicas=4))
-    assert p_old.compiled is p_new.compiled        # behavior unchanged
-    p_new.release()
-
-    sched = Scheduler([Device("b", SPEC)], cache=cache)
-    with pytest.warns(DeprecationWarning, match="Session.compile"):
-        sched.build(POLY1, max_replicas=4)
-
-    # the blessed paths stay silent
-    import warnings as _w
-    with _w.catch_warnings():
-        _w.simplefilter("error", DeprecationWarning)
-        jit_compile(POLY1, SPEC, cache=cache,
-                    opts=CompileOptions(max_replicas=4, seed=1))
-        jit_compile(POLY1, SPEC, cache=cache)      # bare defaults: no knobs
-        sched.build_opts(POLY1, CompileOptions(max_replicas=2))
+    opts = CompileOptions(max_replicas=4)
+    sched = Scheduler([Device("a", SPEC), Device("b", SPEC)], cache=cache)
+    p0 = sched.build(POLY1, opts)
+    assert p0.opts == opts
+    assert sched.contexts[p0.ctx.device.name].ledger_consistent()
+    misses = cache.stats.misses
+    with Session([Device("c", SPEC)], cache=cache) as sess:
+        p1 = sess.compile(POLY1, opts).result(60)
+        assert p1.compiled is p0.compiled                    # cache hit
+    assert cache.stats.misses == misses

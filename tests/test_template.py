@@ -12,6 +12,7 @@ from repro.core.fuse import to_fu_graph
 from repro.core.ir import compile_opencl_to_dfg
 from repro.core.jit import jit_compile
 from repro.core.latency import balance
+from repro.core.options import CompileOptions
 from repro.core.overlay import OverlaySpec, RoutingGraph
 from repro.core.runtime import Device, Scheduler
 from repro.core.template import (build_template, estimate_capacity, gap_fill,
@@ -54,8 +55,10 @@ def test_template_vs_joint_parity(name):
     """Same replica budget through both P&R paths: identical FU/IO usage,
     both legal (no channel overuse), both latency-balanced."""
     src = BENCHMARKS[name][0]
-    ck_t = jit_compile(src, SPEC, max_replicas=4, pr_mode="template")
-    ck_j = jit_compile(src, SPEC, max_replicas=4, pr_mode="joint")
+    ck_t = jit_compile(src, SPEC,
+                       opts=CompileOptions(max_replicas=4, pr_mode="template"))
+    ck_j = jit_compile(src, SPEC,
+                       opts=CompileOptions(max_replicas=4, pr_mode="joint"))
     assert ck_t.pr_path == "template" and ck_j.pr_path == "joint"
     assert ck_t.plan.replicas == ck_j.plan.replicas == 4
     assert ck_t.plan.fus_used == ck_j.plan.fus_used
@@ -73,8 +76,9 @@ def test_stamped_latency_equals_recomputed_stage():
     on the stamped routing — stamping skips the stage losslessly (this is
     the 'identical latency-balance depth' parity claim, exactly)."""
     for spec, r in ((SPEC, 8), (TRUNK_SPEC, 20)):
-        ck = jit_compile(BENCHMARKS["poly1"][0], spec, max_replicas=r,
-                         pr_mode="template")
+        ck = jit_compile(BENCHMARKS["poly1"][0], spec,
+                         opts=CompileOptions(max_replicas=r,
+                                             pr_mode="template"))
         assert ck.plan.replicas == r
         lat = balance(ck.fug, spec, ck.routing)
         assert lat.delays == ck.latency.delays
@@ -84,10 +88,12 @@ def test_stamped_latency_equals_recomputed_stage():
 
 
 def test_template_deterministic_by_seed():
-    a = jit_compile(BENCHMARKS["chebyshev"][0], SPEC, max_replicas=8,
-                    pr_mode="template", seed=3)
-    b = jit_compile(BENCHMARKS["chebyshev"][0], SPEC, max_replicas=8,
-                    pr_mode="template", seed=3)
+    a = jit_compile(BENCHMARKS["chebyshev"][0], SPEC,
+                    opts=CompileOptions(max_replicas=8, pr_mode="template",
+                                        seed=3))
+    b = jit_compile(BENCHMARKS["chebyshev"][0], SPEC,
+                    opts=CompileOptions(max_replicas=8, pr_mode="template",
+                                        seed=3))
     assert a.bitstream.data == b.bitstream.data
     assert a.placement.fu_pos == b.placement.fu_pos
 
@@ -95,7 +101,8 @@ def test_template_deterministic_by_seed():
 def test_trunk_bands_route_and_evaluate():
     """Deep stamp bands (vertical IO trunks across shallower bands) stay
     within channel capacity and compute the right values."""
-    ck = jit_compile(BENCHMARKS["poly1"][0], TRUNK_SPEC, pr_mode="template")
+    ck = jit_compile(BENCHMARKS["poly1"][0], TRUNK_SPEC,
+                     opts=CompileOptions(pr_mode="template"))
     assert ck.plan.replicas > 16          # more than the perimeter-only rows
     assert _channel_overuse(ck, TRUNK_SPEC) == []
     x = np.linspace(-2, 2, 256).astype(np.float32)
@@ -182,14 +189,14 @@ def test_replica_change_on_cached_template_runs_no_par_stage():
     no place/route/latency stage — only stamping."""
     cache = JITCache()
     src = BENCHMARKS["chebyshev"][0]
-    cold = jit_compile(src, SPEC, max_replicas=8, pr_mode="template",
-                       cache=cache)
+    cold = jit_compile(src, SPEC, cache=cache,
+                       opts=CompileOptions(max_replicas=8, pr_mode="template"))
     assert cold.pr_path == "template"
     assert cold.stage_times_ms["place"] > 0          # template was built
     assert cache.stats.template_misses == 1
 
-    warm = jit_compile(src, SPEC, max_replicas=4, pr_mode="template",
-                       cache=cache)
+    warm = jit_compile(src, SPEC, cache=cache,
+                       opts=CompileOptions(max_replicas=4, pr_mode="template"))
     assert warm.plan.replicas == 4                   # genuinely rebuilt
     assert warm is not cold
     assert cache.stats.template_hits == 1
@@ -216,10 +223,11 @@ def test_auto_mode_never_degrades_replication():
     assert ck.pr_path == "template"
     assert "template_probe" not in ck.stage_times_ms    # joint never probed
     uncapped_joint = jit_compile(BENCHMARKS["poly1"][0], SPEC,
-                                 pr_mode="joint")
+                                 opts=CompileOptions(pr_mode="joint"))
     assert ck.plan.replicas >= uncapped_joint.plan.replicas
     # ...and uses the pure stamp when the request is the binding constraint
-    capped = jit_compile(BENCHMARKS["poly1"][0], SPEC, max_replicas=8)
+    capped = jit_compile(BENCHMARKS["poly1"][0], SPEC,
+                         opts=CompileOptions(max_replicas=8))
     assert capped.pr_path == "template"
     assert capped.plan.replicas == 8
     assert "infill" not in capped.stage_times_ms        # stamp grid sufficed
@@ -306,15 +314,18 @@ def test_gap_fill_reaches_full_plan():
     assert relat.delays == ck.latency.delays
     assert relat.out_ready == ck.latency.out_ready
     x = np.linspace(-1, 1, 128).astype(np.float32)
-    ref = jit_compile(BENCHMARKS["chebyshev"][0], spec, max_replicas=1)
+    ref = jit_compile(BENCHMARKS["chebyshev"][0], spec,
+                      opts=CompileOptions(max_replicas=1))
     np.testing.assert_allclose(ck.run_reference(x), ref.run_reference(x),
                                rtol=1e-5)
 
 
 def test_gap_fill_deterministic_by_seed():
     spec = OverlaySpec(width=32, height=8, dsp_per_fu=2)
-    a = jit_compile(BENCHMARKS["chebyshev"][0], spec, seed=5)
-    b = jit_compile(BENCHMARKS["chebyshev"][0], spec, seed=5)
+    a = jit_compile(BENCHMARKS["chebyshev"][0], spec,
+                    opts=CompileOptions(seed=5))
+    b = jit_compile(BENCHMARKS["chebyshev"][0], spec,
+                    opts=CompileOptions(seed=5))
     assert a.stage_times_ms.get("infill", 0.0) > 0.0
     assert a.bitstream.data == b.bitstream.data
     assert a.placement.fu_pos == b.placement.fu_pos
@@ -352,9 +363,11 @@ def test_scheduler_reinflates_on_release():
     """ROADMAP open item: when fabric frees up, shed programs grow back to
     their planned replica count — without any P&R stage rerunning."""
     sched = Scheduler([Device("a", SPEC)])
-    a = sched.build(BENCHMARKS["poly1"][0], max_replicas=16)      # 32 FUs
+    a = sched.build(BENCHMARKS["poly1"][0],
+                    opts=CompileOptions(max_replicas=16))      # 32 FUs
     first = a.compiled
-    c = sched.build(BENCHMARKS["chebyshev"][0], max_replicas=10)  # 30 FUs
+    c = sched.build(BENCHMARKS["chebyshev"][0],
+                    opts=CompileOptions(max_replicas=10))  # 30 FUs
     assert a.compiled.plan.replicas == 16 and a.planned_replicas == 16
     b = sched.build(BENCHMARKS["sgfilter"][0])    # nothing free: sheds a
     assert a.compiled.plan.replicas < 16
@@ -385,8 +398,10 @@ def test_reinflation_restores_victim_when_no_growth_possible():
     """Releasing fabric that does NOT make growth possible must leave every
     shed program resident and the ledger intact."""
     sched = Scheduler([Device("a", SPEC)])
-    a = sched.build(BENCHMARKS["poly1"][0], max_replicas=16)
-    c = sched.build(BENCHMARKS["chebyshev"][0], max_replicas=10)
+    a = sched.build(BENCHMARKS["poly1"][0],
+                    opts=CompileOptions(max_replicas=16))
+    c = sched.build(BENCHMARKS["chebyshev"][0],
+                    opts=CompileOptions(max_replicas=10))
     sched.build(BENCHMARKS["sgfilter"][0])         # sheds a → 8 replicas
     shrunk = a.compiled.plan.replicas
     ctx = sched.contexts["a"]
@@ -419,7 +434,7 @@ def test_cache_miss_does_not_reoptimize_frontend(monkeypatch):
     # python-callable path: lowering for the cache key optimizes exactly
     # once; the frontend stage must not run the pass pipeline again
     calls["n"] = 0
-    ck2 = jit_compile(lambda x: x * 2.0 + 1.0, SPEC, n_inputs=1, name="fn",
-                      cache=cache)
+    ck2 = jit_compile(lambda x: x * 2.0 + 1.0, SPEC, cache=cache,
+                      opts=CompileOptions(n_inputs=1, name="fn"))
     assert cache.stats.misses == 2 and ck2.plan.replicas >= 1
     assert calls["n"] == 1, "callable cache miss paid the frontend twice"

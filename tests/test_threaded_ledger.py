@@ -27,7 +27,7 @@ def test_concurrent_release_never_double_credits():
     be un-booked)."""
     ctx = Context(Device("d", SPEC), cache=JITCache())
     for _ in range(10):
-        prog = ctx.build_program(POLY1, max_replicas=4)
+        prog = ctx.build_program(POLY1, opts=CompileOptions(max_replicas=4))
         used = ctx.device.fu_used
         assert used > 0
         start = threading.Barrier(9)
@@ -57,9 +57,9 @@ def test_release_during_resize_cannot_double_credit():
     rng = random.Random(0)
     for _ in range(3):
         sched = Scheduler([Device("a", SPEC)], cache=cache)
-        big = sched.build_opts(POLY1, CompileOptions(), tenant="big")
-        others = [sched.build_opts(CHEB, CompileOptions(max_replicas=4),
-                                   tenant=f"t{i}") for i in range(2)]
+        big = sched.build(POLY1, CompileOptions(), tenant="big")
+        others = [sched.build(CHEB, CompileOptions(max_replicas=4),
+                              tenant=f"t{i}") for i in range(2)]
         assert big.compiled.plan.replicas < big.planned_replicas  # was shed
         progs = [big] + others
         rng.shuffle(progs)
